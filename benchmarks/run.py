@@ -211,11 +211,8 @@ def main() -> None:
     if not args.skip_pde_suite:
         from benchmarks import pde_suite
         rows += pde_suite.summarize(pde_suite.run(ci=True))
-    try:
-        from benchmarks import roofline
-        rows += roofline.summarize()
-    except Exception as e:  # noqa: BLE001
-        rows.append({"name": "roofline/unavailable", "derived": repr(e)})
+    from benchmarks import roofline
+    rows += roofline.summarize()
 
     print("name,us_per_call,derived")
     for r in rows:

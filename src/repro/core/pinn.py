@@ -42,12 +42,27 @@ from repro import pde as pde_lib
 from repro.core import fastmath, photonic, spectral as spectral_lib, stein, tt
 from repro.kernels import quant as quant_lib
 
+# every matmul of the forward runs in full f32: the FD residual amplifies
+# rounding in u by 1/h² (DESIGN.md §Perf), and TPU's DEFAULT precision
+# rounds f32 operands to bf16
+_HIGHEST = jax.lax.Precision.HIGHEST
+
 __all__ = ["PINNConfig", "TensorPinn", "sample_collocation",
            "residual_loss", "residual_losses_stacked", "per_term_losses",
            "validation_mse", "config_to_meta", "config_from_meta",
            # deprecated HJB-specific aliases
            "HJBPinn", "hjb_exact_solution", "hjb_residual_loss",
            "hjb_residual_losses_stacked"]
+
+
+def _out_head(h: jax.Array, w2: jax.Array) -> jax.Array:
+    """``h @ w2ᵀ`` for the (1 × hidden) output layer, as a row-wise
+    reduction: a row's value then does not depend on how many rows ride
+    along.  A GEMV's rounding does (XLA:CPU tiles it by the batch size),
+    which broke served-vs-direct bit-identity, and the FD stencil amplifies
+    such differences 1/h²-fold.  h: (..., hidden), w2: (1, hidden) or
+    stacked (P, 1, hidden)."""
+    return jnp.sum(h * w2, axis=-1, keepdims=True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,7 +92,7 @@ class PINNConfig:
     #                             problem — they are domain facts)
     use_fused_kernel: bool = False  # route TT matvecs through the Pallas
     #                                 kernel dispatcher (repro.kernels.ops):
-    #                                 fused VMEM chain on TPU, jnp ref on CPU
+    #                                 Pallas kernels on TPU, jnp ref on CPU
     pde: str = "hjb-20d"        # registry name resolved by TensorPinn when
     #                             no problem instance is passed explicitly
     noise: photonic.NoiseModel = dataclasses.field(
@@ -353,7 +368,7 @@ class TensorPinn:
                       x: jax.Array) -> jax.Array:
         cfg = self.cfg
         if cfg.mode == "dense":
-            return x @ params[f"w{i}"].T
+            return jnp.matmul(x, params[f"w{i}"].T, precision=_HIGHEST)
         if cfg.mode == "onn":
             pm = self.photonic[i]
             nz = None if noise is None else noise[f"p{i}"]
@@ -401,7 +416,7 @@ class TensorPinn:
         for i in range(2):
             h = self._layer_matvec(params, noise, i, h) + params[f"b{i}"]
             h = jnp.sin(h)
-        out = h @ params["w2"].T + params["b2"]
+        out = _out_head(h, params["w2"]) + params["b2"]
         return out[..., 0]
 
     def u(self, params: dict, xt: jax.Array, noise: dict | None = None) -> jax.Array:
@@ -443,7 +458,7 @@ class TensorPinn:
         a = jnp.sin(self._layer_matvec(params, noise, 1,
                                        a.reshape(-1, cfg.hidden))
                     + params["b1"])
-        f = (a @ params["w2"].T + params["b2"])[..., 0]
+        f = (_out_head(a, params["w2"]) + params["b2"])[..., 0]
         f = f.reshape(2 * A + 1, B)
         return self.problem.ansatz(f, pde_lib.fd_stencil_points(xt, h, A))
 
@@ -472,8 +487,10 @@ class TensorPinn:
         hardware noise into the densified cores)."""
         cfg = self.cfg
         if cfg.mode == "dense":
-            sub = "bn,pmn->pbm" if x.ndim == 2 else "pbn,pmn->pbm"
-            return jnp.einsum(sub, x, stacked[f"w{i}"])
+            w = stacked[f"w{i}"]
+            if x.ndim == 2:   # per-entry GEMMs, as in tt.tt_matvec_stacked
+                x = jnp.broadcast_to(x, (w.shape[0],) + x.shape)
+            return jnp.einsum("pbn,pmn->pbm", x, w, precision=_HIGHEST)
         if cfg.mode == "onn":
             pm = self.photonic[i]
             nz = None if noise is None else noise[f"p{i}"]
@@ -501,17 +518,16 @@ class TensorPinn:
         z1 only feeds an elementwise sin and the w2 reduction: we permute
         b1/w2 (1024 floats) instead of the (P, B', 1024) activations.
         On TPU (pallas/interpret dispatch) the stacked contraction kernel
-        already keeps the chain VMEM-resident, so it is used instead.
+        already builds W in VMEM, so it is used instead.
         """
+        from repro.kernels import ops
         cfg = self.cfg
         P, Bp, _ = a.shape
         # Kronecker head is part of the fused hot path only: the unfused
         # stacked sweep stays bit-comparable with the sequential one
         use_kron = (cfg.use_fused_kernel and cfg.mode in ("tt", "tonn")
-                    and self._kron_split is not None)
-        if use_kron:
-            from repro.kernels import ops
-            use_kron = ops.kernel_mode() == "ref"
+                    and self._kron_split is not None
+                    and ops.kernel_mode() == "ref")
         if use_kron:
             spec = self.specs[1]
             k = self._kron_split
@@ -530,22 +546,28 @@ class TensorPinn:
             MR, NR = right.out_dim, right.in_dim
             x = a.reshape(P, Bp * NL, NR)
             x = jax.lax.dot_general(x, wr, (((2,), (2,)), ((0,), (0,))),
+                                    precision=_HIGHEST,
                                     preferred_element_type=jnp.float32)
             x = x.reshape(P, Bp, NL, MR)
             z = jax.lax.dot_general(x, wl, (((2,), (2,)), ((0,), (0,))),
+                                    precision=_HIGHEST,
                                     preferred_element_type=jnp.float32)
             z = z.reshape(P, Bp, cfg.hidden)   # column index = i_R·ML + i_L
             b1p = stacked["b1"].reshape(P, ML, MR) \
                 .transpose(0, 2, 1).reshape(P, cfg.hidden)
             w2p = stacked["w2"].reshape(P, ML, MR) \
                 .transpose(0, 2, 1).reshape(P, 1, cfg.hidden)
-            a2 = self._sin(z + b1p[:, None])
-            f = jnp.einsum("pbh,poh->pbo", a2, w2p)
         else:
-            z = self._layer_matvec_stacked(stacked, 1, a, noise) \
-                + stacked["b1"][:, None]
-            a2 = self._sin(z)
-            f = jnp.einsum("pbh,poh->pbo", a2, stacked["w2"])
+            z = self._layer_matvec_stacked(stacked, 1, a, noise)
+            b1p, w2p = stacked["b1"], stacked["w2"]
+        if ops.kernel_mode() == "pallas":
+            # XLA on TPU tiles a stacked reduction's output by P, so entry
+            # p's rounding would follow the stack size (DESIGN.md
+            # §Distributed); a loop body's shapes do not depend on P
+            f = jax.lax.map(lambda e: _out_head(self._sin(e[0] + e[1]), e[2]),
+                            (z, b1p, w2p))
+        else:
+            f = _out_head(self._sin(z + b1p[:, None]), w2p)
         return (f + stacked["b2"][:, None])[..., 0]
 
     def fd_u_stencil_stacked(self, stacked: dict, xt: jax.Array,

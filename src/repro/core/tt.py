@@ -172,8 +172,8 @@ def tt_init(key, spec: TTSpec, dtype=jnp.float32, scale: float | None = None) ->
     return cores
 
 
-def tt_matvec(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
-              precision=None) -> jax.Array:
+def tt_matvec(cores: Sequence[jax.Array], x: jax.Array,
+              spec: TTSpec) -> jax.Array:
     """Compute ``y = x @ W(cores)^T`` without materializing ``W``.
 
     x: (..., N) → y: (..., M).  Invariant maintained over the chain:
@@ -181,7 +181,9 @@ def tt_matvec(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
         A_{k}: (B, m_1..m_k, r_k, n_{k+1}..n_L)
 
     each step contracts ``(r_{k-1}, n_k)`` with core ``G_k`` as one matmul
-    of shape (B·M_<k, r·n_k) @ (r·n_k, m_k·r') batched over N_>k.
+    of shape (B·M_<k, r·n_k) @ (r·n_k, m_k·r') batched over N_>k, at
+    HIGHEST precision: the FD residual amplifies rounding in the output by
+    1/h² (DESIGN.md §Perf), and TPU's DEFAULT rounds f32 operands to bf16.
     """
     batch_shape = x.shape[:-1]
     B = int(np.prod(batch_shape)) if batch_shape else 1
@@ -195,7 +197,8 @@ def tt_matvec(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
         a = a.reshape(B * m_prefix, r * n_k, n_suffix)
         g = jnp.transpose(cores[k], (0, 2, 1, 3)).reshape(r * n_k, m_k * r_next)
         # (B·m_prefix, n_suffix, r·n_k) @ (r·n_k, m_k·r') -> (B·m_prefix, n_suffix, m_k·r')
-        a = jnp.einsum("abc,bd->acd", a, g, precision=precision)
+        a = jnp.einsum("abc,bd->acd", a, g,
+                       precision=jax.lax.Precision.HIGHEST)
         # reorder so produced m_k joins the m-prefix and r' precedes the n-suffix:
         a = a.reshape(B * m_prefix, n_suffix, m_k, r_next)
         a = jnp.transpose(a, (0, 2, 3, 1))  # (B·m_prefix, m_k, r', n_suffix)
@@ -204,8 +207,8 @@ def tt_matvec(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
     return y.reshape(*batch_shape, spec.out_dim)
 
 
-def tt_matvec_stacked(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
-                      precision=None) -> jax.Array:
+def tt_matvec_stacked(cores: Sequence[jax.Array], x: jax.Array,
+                      spec: TTSpec) -> jax.Array:
     """``tt_matvec`` over a leading stack axis P on the cores (the unfused
     oracle for ``repro.kernels.tt_contract.tt_contract_batched``).
 
@@ -218,9 +221,13 @@ def tt_matvec_stacked(cores: Sequence[jax.Array], x: jax.Array, spec: TTSpec,
     any f32 reassociation by 1/h²; see DESIGN.md §Perf).  The *fast* CPU
     hidden-layer path is the Kronecker head in ``HJBPinn._f_head_stacked``.
     """
-    x_axis = 0 if x.ndim == 3 else None
-    return jax.vmap(lambda c, xx: tt_matvec(c, xx, spec, precision),
-                    in_axes=(0, x_axis))(list(cores), x)
+    if x.ndim == 2:
+        # a shared x is broadcast, not closed over: every entry then runs
+        # its own batched GEMM, so entry p's result does not depend on the
+        # stack size (XLA:CPU folds an unbatched operand's stack axis into
+        # the GEMM width, which changes the rounding with P)
+        x = jnp.broadcast_to(x, (cores[0].shape[0],) + x.shape)
+    return jax.vmap(lambda c, xx: tt_matvec(c, xx, spec))(list(cores), x)
 
 
 def tt_to_full(cores: Sequence[jax.Array], spec: TTSpec) -> jax.Array:

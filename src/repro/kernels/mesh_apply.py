@@ -57,43 +57,41 @@ def mesh_perm_onehot(layout: ph_lib.MeshLayout) -> np.ndarray:
     return onehot
 
 
-def _kernel(levels: int, ports: int, transpose: bool, shared_x: bool,
-            *refs):
+def _kernel(levels: int, transpose: bool, shared_x: bool, *refs):
     x_ref, cos_ref, sin_ref, perm_ref, diag_ref, o_ref = refs
-    x = x_ref[...]
-    if not shared_x:                         # (1, bt, P) block → (bt, P)
-        x = x.reshape(x.shape[-2], x.shape[-1])
+    x = x_ref[...] if shared_x else x_ref[0]
     x = x.astype(jnp.float32)
-    d = diag_ref[...].reshape(ports)
-    cos = cos_ref[...].reshape(levels, ports)
-    sin = sin_ref[...].reshape(levels, ports)
+    d = diag_ref[0]                          # (1, P)
     if not transpose:
-        x = x * d[None, :]
+        x = x * d
     for c in range(levels):                  # static unroll over the chain
+        # HIGHEST keeps the one-hot gather exact on the MXU
         xg = jax.lax.dot_general(x, perm_ref[c], (((1,), (0,)), ((), ())),
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
-        x = cos[c][None, :] * x + sin[c][None, :] * xg
+        x = cos_ref[0, c:c + 1, :] * x + sin_ref[0, c:c + 1, :] * xg
     if transpose:
-        x = x * d[None, :]
-    o_ref[...] = x.reshape(o_ref.shape).astype(o_ref.dtype)
+        x = x * d
+    o_ref[0] = x.astype(o_ref.dtype)
 
 
-def default_batch_tile(ports: int, levels: int,
-                       vmem_budget_bytes: int = 4 * 2**20) -> int:
-    """Largest batch tile whose resident set (x + out tiles; the trig and
-    permutation tables are batch-independent) fits the VMEM budget."""
+def _batch_tile(rows: int, ports: int, levels: int,
+                vmem_budget_bytes: int = 4 * 2**20) -> int:
+    """Balanced batch tile whose resident set (x + out tiles; the trig and
+    permutation tables are batch-independent) fits the VMEM budget: the
+    whole batch if it fits, else equal tiles of a multiple of 8 rows."""
     fixed = (2 * levels * ports + levels * ports * ports) * 4
     per_row = 2 * ports * 4
-    bt = max(8, (vmem_budget_bytes - fixed) // max(per_row, 1))
-    if bt >= 128:
-        bt = (bt // 128) * 128
-    return min(int(bt), 2048)
+    cap = max(8, min(2048, (vmem_budget_bytes - fixed) // per_row) // 8 * 8)
+    if rows <= cap:
+        return rows
+    n_tiles = -(-rows // cap)
+    return -(-(-(-rows // n_tiles)) // 8) * 8
 
 
 def mesh_apply_stacked_pallas(layout: ph_lib.MeshLayout, phases: jax.Array,
                               diag: jax.Array, x: jax.Array,
                               transpose: bool = False,
-                              batch_tile: int | None = None,
                               interpret: bool = False) -> jax.Array:
     """Kernel-backed ``photonic.mesh_apply_stacked``: phases
     ``(S, levels, slots)``, diag ``(P,)`` or ``(S, P)``, x ``(B, P)``
@@ -111,16 +109,10 @@ def mesh_apply_stacked_pallas(layout: ph_lib.MeshLayout, phases: jax.Array,
     if transpose:
         onehot = np.ascontiguousarray(onehot[::-1])
         # tables are already level-reversed/negated by mesh_gather_tables
-    diag2 = jnp.broadcast_to(diag, (S, Pw)) if diag.ndim == 1 else diag
+    # (S, 1, P): every block's last two dims are whole array dims
+    diag3 = jnp.broadcast_to(diag, (S, Pw)).reshape(S, 1, Pw)
 
-    bt = batch_tile or default_batch_tile(Pw, levels)
-    bt = min(bt, B)
-    Bp = ((B + bt - 1) // bt) * bt
-    if Bp != B:
-        pad = [(0, 0)] * (x.ndim - 2) + [(0, Bp - B), (0, 0)]
-        x = jnp.pad(x, pad)
-
-    grid = (S, Bp // bt)
+    bt = _batch_tile(B, Pw, levels)
     if shared_x:
         in_specs = [pl.BlockSpec((bt, Pw), lambda s, i: (i, 0))]
     else:
@@ -129,16 +121,14 @@ def mesh_apply_stacked_pallas(layout: ph_lib.MeshLayout, phases: jax.Array,
         pl.BlockSpec((1, levels, Pw), lambda s, i: (s, 0, 0)),   # cos
         pl.BlockSpec((1, levels, Pw), lambda s, i: (s, 0, 0)),   # sin
         pl.BlockSpec((levels, Pw, Pw), lambda s, i: (0, 0, 0)),  # perm
-        pl.BlockSpec((1, Pw), lambda s, i: (s, 0)),              # diag
+        pl.BlockSpec((1, 1, Pw), lambda s, i: (s, 0, 0)),        # diag
     ]
-    out_spec = pl.BlockSpec((1, bt, Pw), lambda s, i: (s, i, 0))
-
-    y = pl.pallas_call(
-        functools.partial(_kernel, levels, Pw, transpose, shared_x),
-        grid=grid,
+    return pl.pallas_call(
+        functools.partial(_kernel, levels, transpose, shared_x),
+        grid=(S, pl.cdiv(B, bt)),
         in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Bp, Pw), x.dtype),
+        out_specs=pl.BlockSpec((1, bt, Pw), lambda s, i: (s, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((S, B, Pw), x.dtype),
         interpret=interpret,
-    )(x, cos, sin, jnp.asarray(onehot), diag2)
-    return y[:, :B]
+        name="mesh_apply_stacked",
+    )(x, cos, sin, jnp.asarray(onehot), diag3)

@@ -26,8 +26,9 @@ from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
 from repro.kernels import tt_contract as _ttc
 
-__all__ = ["kernel_mode", "tt_linear", "tt_linear_batched",
-           "mesh_apply_stacked", "attention", "KERNEL_MODES"]
+__all__ = ["kernel_mode", "tt_impl", "mesh_impl", "tt_linear",
+           "tt_linear_batched", "mesh_apply_stacked", "attention",
+           "KERNEL_MODES"]
 
 KERNEL_MODES = ("pallas", "interpret", "ref")
 
@@ -41,12 +42,6 @@ MESH_KERNEL_MAX_LEVELS = 128
 MESH_KERNEL_MAX_ONEHOT_BYTES = 2 * 2**20
 
 
-def _mesh_kernel_applicable(layout) -> bool:
-    return (layout.levels <= MESH_KERNEL_MAX_LEVELS
-            and 4 * layout.levels * layout.ports * layout.ports
-            <= MESH_KERNEL_MAX_ONEHOT_BYTES)
-
-
 def kernel_mode() -> str:
     mode = os.environ.get("REPRO_KERNEL_MODE")
     if mode:
@@ -58,13 +53,30 @@ def kernel_mode() -> str:
     return "pallas" if jax.default_backend() == "tpu" else "ref"
 
 
+def tt_impl(spec: tt_lib.TTSpec, mode: str | None = None) -> str:
+    """Implementation the TT dispatchers take for ``spec``: the kernel mode,
+    or "ref" when W and its tables do not fit the kernel's VMEM budget."""
+    mode = mode or kernel_mode()
+    return mode if mode == "ref" or _ttc.fits_vmem(spec) else "ref"
+
+
+def mesh_impl(layout, mode: str | None = None) -> str:
+    """Implementation ``mesh_apply_stacked`` takes for ``layout``: the
+    kernel mode, or "ref" for deep or wide meshes."""
+    mode = mode or kernel_mode()
+    fits = (layout.levels <= MESH_KERNEL_MAX_LEVELS
+            and 4 * layout.levels * layout.ports * layout.ports
+            <= MESH_KERNEL_MAX_ONEHOT_BYTES)
+    return mode if fits else "ref"
+
+
 def _weight_quant(quant) -> bool:
     return quant is not None and quant.weights
 
 
 def tt_linear(x: jax.Array, cores: Sequence[jax.Array], spec: tt_lib.TTSpec,
               mode: str | None = None, quant=None) -> jax.Array:
-    mode = mode or kernel_mode()
+    mode = tt_impl(spec, mode)
     if _weight_quant(quant):
         if mode == "ref":
             return _ref.tt_contract_quant_ref(x, cores, spec, quant)
@@ -94,9 +106,10 @@ def tt_linear_batched(x: jax.Array, cores: Sequence[jax.Array],
     With weight quantization on (``quant.weights``), ref mode fake-quants
     in pure jnp (the CPU oracle) and pallas/interpret dispatch to the
     narrow-dtype kernel that dequantizes block-scaled cores in VMEM —
-    both see bit-identical weights and accumulate f32.
+    both see bit-identical weights and accumulate f32.  Specs whose dense W
+    does not fit VMEM take the jnp chain (``tt_impl``).
     """
-    mode = mode or kernel_mode()
+    mode = tt_impl(spec, mode)
     if _weight_quant(quant):
         if mode == "ref":
             return _ref.tt_contract_batched_quant_ref(x, cores, spec, quant,
@@ -133,10 +146,10 @@ def mesh_apply_stacked(layout, phases: jax.Array, diag: jax.Array,
     ``PhotonicMatrix`` quantize before the noise model instead and pass
     quant=None here — idempotence makes the double hook safe anyway.)
     """
-    mode = mode or kernel_mode()
+    mode = mesh_impl(layout, mode)
     if quant is not None and quant.phases:
         phases = _quant.quantize_phases(phases, quant.phase_bits)
-    if mode == "ref" or not _mesh_kernel_applicable(layout):
+    if mode == "ref":
         return _ph.mesh_apply_stacked(layout, phases, diag, x, transpose)
     return _mesh.mesh_apply_stacked_pallas(layout, phases, diag, x,
                                            transpose=transpose,

@@ -1,139 +1,192 @@
-"""Fused TT-chain contraction Pallas kernel — the TONN compute primitive.
+"""Fused TT-linear Pallas kernels — the TONN compute primitive.
 
 The paper's photonic TONN-1 design (Fig. 2) multiplies an input by ALL
 TT-cores in one optical pass: intermediates never leave the chip.  The TPU
-analogue (DESIGN.md §2): a naive jnp chain materializes every intermediate
-``(B·M_<k, r·n_k, N_>k)`` tensor in HBM; this kernel keeps the whole chain
-resident in VMEM for one batch tile, so HBM traffic is exactly
-``B·N + B·M + Σ|G_k|`` bytes — the roofline minimum.
+analogue (DESIGN.md §2): HBM carries the activations and the tiny TT-cores,
+and everything in between stays in VMEM.
 
-Tiling: grid over the flattened batch; each program holds
-  * its ``(bt, N)`` input tile,
-  * every TT-core (they are tiny — the paper's whole point),
-  * the ``(bt, M)`` output tile
-in VMEM.  The per-step matmuls have contracted dims ``r·n_k`` (≤ ~128 for
-practical specs); the batch-tile dim ``bt`` is the MXU-aligned (≥128) axis.
+TPU layout.  The per-core chain of ``tt_matvec`` contracts dims of r·n_k ≈ 8
+and rotates the feature index at every step: a relayout of sub-tile minor
+dims that Mosaic refuses and that the 128×128 MXU could not use anyway.  So
+each kernel rebuilds the dense ``W = W(cores)`` (M × N) in a VMEM scratch,
+with 2-D matmuls only, once per core set, and then streams the batch tiles
+through ONE MXU matmul ``y = x @ Wᵀ`` each:
 
-VMEM budget: bt·(N + M + max intermediate)·4B; choose bt so this stays ≲8 MB
-(``default_batch_tile``).
+  * core k arrives as a flat row ``g (1, |G_k|)`` in its natural
+    (r, m, n, r') order;
+  * for each rank pair (a, b) the slab ``Z[μ, j] = G_k[a, μ, ν_k(j), b]``
+    (m_k × N) is ``(g ⊙ mask_ab) @ C_k``, where the static one-hot ``C_k``
+    sends a core entry to every input column whose k-th digit is its n index;
+  * ``E_ab = R_k @ Z`` repeats the slab over the output rows (``R_k`` one-hot
+    on the k-th output digit), and the rank sum ``W = Σ_paths ⊙_k E_k``
+    contracts the chain elementwise, one block of output rows at a time.
 
-``tt_contract_batched`` extends the grid with a leading *perturbation* axis
-``P``: each core carries P stacked variants (one per SPSA sample) and the
-grid is ``(P, batch-tiles)``, so an entire ZO loss sweep — all N perturbed
-models — executes as ONE kernel launch instead of N sequential unfused
-chains (DESIGN.md §Perf).  The input may be shared across P (its BlockSpec
-index map simply ignores the p coordinate — zero extra HBM traffic) or carry
-its own P axis (layer ≥ 2, where activations differ per perturbation).
+Every matmul runs at ``Precision.HIGHEST``: the one-hot products are then
+exact, and the FD residual, which amplifies rounding in u by 1/h²
+(DESIGN.md §Perf), sees f32 arithmetic as on the jnp oracle.
+
+HBM traffic per call is the x tiles, the y tiles, the cores and the one-hot
+tables; the tables are read once per call, whatever the batch.
+
+``tt_contract_batched`` adds a leading perturbation axis P: grid
+``(P, batch-tiles)``, one core set per ``p`` and W rebuilt at the first batch
+tile of every ``p``, so an entire ZO loss sweep (all N+1 perturbed models) is
+ONE launch.  x may be shared across P (its index map ignores p) or carry its
+own P axis.  ``tt_contract`` is the P = 1 case.  ``fits_vmem`` says whether a
+spec's W and tables fit the kernel's VMEM budget; ``repro.kernels.ops`` sends
+larger specs to the jnp chain.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import tt as tt_lib
 from repro.kernels import quant as quant_lib
 
 __all__ = ["tt_contract", "tt_contract_batched",
-           "tt_contract_batched_quant", "default_batch_tile"]
+           "tt_contract_batched_quant", "fits_vmem"]
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# x + y tiles, double-buffered, per batch row budget
+_TILE_BUDGET_BYTES = 8 * 2**20
+# W scratch + one-hot tables (double-buffered) + build temporaries
+_VMEM_BUDGET_BYTES = 48 * 2**20
+_VMEM_LIMIT_BYTES = 100 * 2**20
+# output rows of W built per block (bounds the build temporaries)
+_W_ROW_BLOCK = 256
 
 
-def _chain(x_tile: jax.Array, cores: Sequence[jax.Array],
-           spec: tt_lib.TTSpec) -> jax.Array:
-    """The contraction chain on one resident tile (same math as tt_matvec)."""
-    bt = x_tile.shape[0]
-    n_suffix = spec.in_dim
-    m_prefix = 1
-    a = x_tile.reshape(bt, 1, spec.in_dim)
-    for k in range(spec.L):
-        r, m_k, n_k, r_next = spec.core_shapes[k]
-        n_suffix //= n_k
-        a = a.reshape(bt * m_prefix, r * n_k, n_suffix)
-        g = jnp.transpose(cores[k], (0, 2, 1, 3)).reshape(r * n_k, m_k * r_next)
-        a = jax.lax.dot_general(
-            a, g, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)      # (B', N_>k, m·r')
-        a = a.reshape(bt * m_prefix, n_suffix, m_k, r_next)
-        a = jnp.transpose(a, (0, 2, 3, 1))
-        m_prefix *= m_k
-    return a.reshape(bt, spec.out_dim)
+def _dot(a: jax.Array, b: jax.Array, trans_b: bool = False) -> jax.Array:
+    dims = (((1,), (1 if trans_b else 0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims, precision=_HIGHEST,
+                               preferred_element_type=jnp.float32)
 
 
-def _kernel(spec: tt_lib.TTSpec, n_cores: int, *refs):
-    x_ref = refs[0]
-    core_refs = refs[1:1 + n_cores]
-    o_ref = refs[1 + n_cores]
-    cores = [c[...] for c in core_refs]
-    y = _chain(x_ref[...].astype(jnp.float32), cores, spec)
-    o_ref[...] = y.astype(o_ref.dtype)
+def _digit(modes: tuple, k: int) -> np.ndarray:
+    """k-th mixed-radix digit (most significant first) of every index."""
+    idx = np.arange(int(np.prod(modes)))
+    return (idx // int(np.prod(modes[k + 1:]))) % modes[k]
 
 
-def default_batch_tile(spec: tt_lib.TTSpec, vmem_budget_bytes: int = 8 * 2**20) -> int:
-    """Largest MXU-aligned batch tile whose chain working set fits VMEM."""
-    # widest intermediate along the chain (elements per batch row)
-    widest = max(spec.in_dim, spec.out_dim)
-    m_prefix, n_suffix = 1, spec.in_dim
-    for k in range(spec.L):
-        r, m_k, n_k, r_next = spec.core_shapes[k]
-        n_suffix //= n_k
-        widest = max(widest, m_prefix * m_k * r_next * n_suffix)
-        m_prefix *= m_k
-    per_row = (spec.in_dim + spec.out_dim + 2 * widest) * 4
-    bt = max(8, int(vmem_budget_bytes // max(per_row, 1)))
-    # round down to a multiple of 128 (MXU lane alignment) when possible
-    if bt >= 128:
-        bt = (bt // 128) * 128
-    return min(bt, 4096)
+@functools.lru_cache(maxsize=None)
+def _tables(spec: tt_lib.TTSpec, flat_lens: tuple) -> tuple:
+    """Static one-hot tables per core: ``R (M, m)``, ``C (F, N)`` and
+    ``masks (r·r', m, F)``, with F the (possibly padded) flat core length."""
+    out = []
+    for k, shape in enumerate(spec.core_shapes):
+        r, m, n, rn = shape
+        size = int(np.prod(shape))
+        a, mu, nu, b = np.unravel_index(np.arange(size), shape)
+        rows = (_digit(spec.out_modes, k)[:, None]
+                == np.arange(m)[None]).astype(np.float32)
+        cols = np.zeros((flat_lens[k], spec.in_dim), np.float32)
+        cols[:size] = nu[:, None] == _digit(spec.in_modes, k)[None]
+        masks = np.zeros((r * rn, m, flat_lens[k]), np.float32)
+        masks[a * rn + b, mu, np.arange(size)] = 1.0
+        out.append((rows, cols, masks))
+    return tuple(out)
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "batch_tile", "interpret"))
-def tt_contract(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
-                batch_tile: int | None = None,
-                interpret: bool = False) -> jax.Array:
-    """y = x @ W(cores)^T, fused in VMEM.  x: (..., N) → (..., M)."""
-    batch_shape = x.shape[:-1]
-    B = int(np.prod(batch_shape)) if batch_shape else 1
-    xf = x.reshape(B, spec.in_dim)
-    bt = batch_tile or default_batch_tile(spec)
-    bt = min(bt, B)
-    # pad batch to a tile multiple
-    Bp = ((B + bt - 1) // bt) * bt
-    if Bp != B:
-        xf = jnp.pad(xf, ((0, Bp - B), (0, 0)))
-
-    grid = (Bp // bt,)
-    in_specs = [pl.BlockSpec((bt, spec.in_dim), lambda i: (i, 0))]
-    for shape in spec.core_shapes:
-        in_specs.append(pl.BlockSpec(shape, lambda i: (0, 0, 0, 0)))
-    out_spec = pl.BlockSpec((bt, spec.out_dim), lambda i: (i, 0))
-
-    y = pl.pallas_call(
-        functools.partial(_kernel, spec, spec.L),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((Bp, spec.out_dim), x.dtype),
-        interpret=interpret,
-    )(xf, *cores)
-    return y[:B].reshape(*batch_shape, spec.out_dim)
+def _table_bytes(spec: tt_lib.TTSpec, flat_lens: tuple) -> int:
+    lanes = lambda d: -(-d // 128) * 128
+    total = 0
+    for (r, m, _, rn), f in zip(spec.core_shapes, flat_lens):
+        total += spec.out_dim * lanes(m) + f * lanes(spec.in_dim) \
+            + r * rn * 8 * lanes(f)
+    return 4 * total
 
 
-def _batched_kernel(spec: tt_lib.TTSpec, n_cores: int, shared_x: bool, *refs):
-    x_ref = refs[0]
-    core_refs = refs[1:1 + n_cores]
-    o_ref = refs[1 + n_cores]
-    xt = x_ref[...]
-    if not shared_x:                       # (1, bt, N) block → (bt, N)
-        xt = xt.reshape(xt.shape[-2], xt.shape[-1])
-    cores = [c[...].reshape(spec.core_shapes[k])
-             for k, c in enumerate(core_refs)]
-    y = _chain(xt.astype(jnp.float32), cores, spec)
-    o_ref[...] = y.reshape(o_ref.shape).astype(o_ref.dtype)
+def fits_vmem(spec: tt_lib.TTSpec) -> bool:
+    """True iff W, the one-hot tables and the build temporaries fit VMEM."""
+    flat = tuple(int(np.prod(s)) for s in spec.core_shapes)
+    w_bytes = 4 * spec.out_dim * spec.in_dim
+    rank = max(spec.ranks)
+    build = 4 * min(spec.out_dim, _W_ROW_BLOCK) * spec.in_dim * (2 * rank + 2)
+    return w_bytes + 2 * _table_bytes(spec, flat) + build \
+        <= _VMEM_BUDGET_BYTES
+
+
+def _row_block(m_dim: int) -> int:
+    if m_dim <= _W_ROW_BLOCK:
+        return m_dim
+    for tm in range(_W_ROW_BLOCK, 7, -8):
+        if m_dim % tm == 0:
+            return tm
+    return m_dim
+
+
+def _batch_tile(rows: int, spec: tt_lib.TTSpec) -> int:
+    """Balanced batch tile: as many tiles as the budget needs, each a
+    multiple of 16 rows (bf16 tiling), or the whole batch if it fits."""
+    per_row = 2 * 4 * (spec.in_dim + spec.out_dim)
+    cap = max(16, (_TILE_BUDGET_BYTES // per_row) // 16 * 16)
+    if rows <= cap:
+        return rows
+    n_tiles = -(-rows // cap)
+    return -(-(-(-rows // n_tiles)) // 16) * 16
+
+
+def _build_w(spec: tt_lib.TTSpec, cores: list, tables: list, w_ref) -> None:
+    """W (M × N) from the flat core rows, into the VMEM scratch."""
+    slabs = []
+    for k, (r, m, _, rn) in enumerate(spec.core_shapes):
+        _, cols_ref, masks_ref = tables[k]
+        g = jnp.broadcast_to(cores[k], (m, cores[k].shape[-1]))
+        cols = cols_ref[...]
+        slabs.append([[_dot(g * masks_ref[a * rn + b], cols)
+                       for b in range(rn)] for a in range(r)])
+    tm = _row_block(spec.out_dim)
+    for r0 in range(0, spec.out_dim, tm):
+        acc = None                       # one (tm, N) block per open rank
+        for k, (r, _, _, rn) in enumerate(spec.core_shapes):
+            rows = tables[k][0][r0:r0 + tm, :]
+            e = [[_dot(rows, slabs[k][a][b]) for b in range(rn)]
+                 for a in range(r)]
+            if acc is None:
+                acc = e[0]
+                continue
+            nxt = []
+            for b in range(rn):
+                t = acc[0] * e[0][b]
+                for a in range(1, r):
+                    t = t + acc[a] * e[a][b]
+                nxt.append(t)
+            acc = nxt
+        w_ref[r0:r0 + tm, :] = acc[0]
+
+
+def _kernel(spec: tt_lib.TTSpec, shared_x: bool, quantized: bool, *refs):
+    L = spec.L
+    x_ref, refs = refs[0], refs[1:]
+    if quantized:
+        q_refs, s_refs, e_refs = refs[:L], refs[L:2 * L], refs[2 * L:3 * L]
+        refs = refs[3 * L:]
+    else:
+        c_refs, refs = refs[:L], refs[L:]
+    tables = [refs[3 * k:3 * k + 3] for k in range(L)]
+    o_ref, w_ref = refs[3 * L:]
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        if quantized:
+            # dequantize in VMEM: each code times its block's f32 scale,
+            # the scale spread over its block by a one-hot matmul (exact)
+            cores = [q_refs[k][0].astype(jnp.float32)
+                     * _dot(s_refs[k][0], e_refs[k][...]) for k in range(L)]
+        else:
+            cores = [c[0].astype(jnp.float32) for c in c_refs]
+        _build_w(spec, cores, tables, w_ref)
+
+    x = x_ref[...] if shared_x else x_ref[0]
+    y = _dot(x.astype(jnp.float32), w_ref[...], trans_b=True)
+    o_ref[0] = y.astype(o_ref.dtype)
 
 
 def _split_batch_axes(x: jax.Array, P: int, spec: tt_lib.TTSpec,
@@ -157,10 +210,39 @@ def _split_batch_axes(x: jax.Array, P: int, spec: tt_lib.TTSpec,
     return x.reshape(P, -1, spec.in_dim), batch_shape, False
 
 
-@functools.partial(jax.jit, static_argnames=("spec", "batch_tile",
-                                             "interpret", "shared_x"))
+def _launch(x: jax.Array, core_args: list, core_specs: list,
+            spec: tt_lib.TTSpec, P: int, flat_lens: tuple, shared_x: bool,
+            quantized: bool, interpret: bool) -> jax.Array:
+    """One ``pallas_call`` over the ``(P, batch-tiles)`` grid."""
+    B = x.shape[-2]
+    bt = _batch_tile(B, spec)
+    const = lambda a: pl.BlockSpec(a.shape, lambda p, i: (0,) * a.ndim)
+    tables = [jnp.asarray(t) for tab in _tables(spec, flat_lens) for t in tab]
+    if shared_x:
+        x_spec = pl.BlockSpec((bt, spec.in_dim), lambda p, i: (i, 0))
+    else:
+        x_spec = pl.BlockSpec((1, bt, spec.in_dim), lambda p, i: (p, i, 0))
+    return pl.pallas_call(
+        functools.partial(_kernel, spec, shared_x, quantized),
+        grid=(P, pl.cdiv(B, bt)),
+        in_specs=[x_spec] + core_specs + [const(t) for t in tables],
+        out_specs=pl.BlockSpec((1, bt, spec.out_dim), lambda p, i: (p, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((P, B, spec.out_dim), x.dtype),
+        scratch_shapes=[pltpu.VMEM((spec.out_dim, spec.in_dim), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=interpret,
+        name="tt_contract_quant" if quantized else "tt_contract",
+    )(x, *core_args, *tables)
+
+
+def _row_spec(length: int) -> pl.BlockSpec:
+    return pl.BlockSpec((1, 1, length), lambda p, i: (p, 0, 0))
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "interpret", "shared_x"))
 def tt_contract_batched(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
-                        batch_tile: int | None = None,
                         interpret: bool = False,
                         shared_x: bool | None = None) -> jax.Array:
     """``y[p] = x[p] @ W(cores[p])^T`` for P stacked core-sets, one launch.
@@ -177,80 +259,32 @@ def tt_contract_batched(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
     the output reshaped back to ``(P, *batch_axes, M)``.  ``shared_x``
     disambiguates when inference from rank alone is ambiguous (None keeps
     the legacy rule: rank 2 = shared, otherwise per-P).
-
-    Grid ``(P, B/bt)``; each program holds ONE perturbation's (tiny) cores
-    plus one batch tile in VMEM, so HBM traffic for the shared-x case is
-    ``B·N + P·(B·M + Σ|G_k|)`` — the input is read once per (p, tile), never
-    duplicated P× in HBM.
     """
     if not cores:
         raise ValueError("need at least one core stack")
     P = cores[0].shape[0]
     x, batch_shape, shared_x = _split_batch_axes(x, P, spec, shared_x)
-    B = x.shape[-2]
-    bt = batch_tile or default_batch_tile(spec)
-    bt = min(bt, B)
-    Bp = ((B + bt - 1) // bt) * bt
-    if Bp != B:
-        pad = [(0, 0)] * (x.ndim - 2) + [(0, Bp - B), (0, 0)]
-        x = jnp.pad(x, pad)
-    # flatten each core stack to (P, |G_k|): rank-2 blocks lower on TPU
-    # regardless of chain length; the kernel reshapes back per-program
-    flat = [c.reshape(P, -1) for c in cores]
-
-    grid = (P, Bp // bt)
-    if shared_x:
-        in_specs = [pl.BlockSpec((bt, spec.in_dim), lambda p, i: (i, 0))]
-    else:
-        in_specs = [pl.BlockSpec((1, bt, spec.in_dim), lambda p, i: (p, i, 0))]
-    for shape in spec.core_shapes:
-        size = int(np.prod(shape))
-        in_specs.append(
-            pl.BlockSpec((1, size), lambda p, i: (p, 0)))
-    out_spec = pl.BlockSpec((1, bt, spec.out_dim), lambda p, i: (p, i, 0))
-
-    y = pl.pallas_call(
-        functools.partial(_batched_kernel, spec, spec.L, shared_x),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((P, Bp, spec.out_dim), x.dtype),
-        interpret=interpret,
-    )(x, *flat)
-    return y[:, :B].reshape((P,) + batch_shape + (spec.out_dim,))
+    flat_lens = tuple(int(np.prod(s)) for s in spec.core_shapes)
+    flat = [c.reshape(P, 1, -1) for c in cores]
+    y = _launch(x, flat, [_row_spec(f) for f in flat_lens], spec, P,
+                flat_lens, shared_x, False, interpret)
+    return y.reshape((P,) + batch_shape + (spec.out_dim,))
 
 
-def _batched_quant_kernel(spec: tt_lib.TTSpec, n_cores: int, shared_x: bool,
-                          block: int, core_sizes: tuple, *refs):
-    """The batched chain with block-scaled narrow-dtype cores: dequantize
-    each core in VMEM (one multiply per block against its f32 scale), then
-    run the identical f32-accumulation chain.  Activations and
-    intermediates stay f32 — only the resident weight bytes narrow."""
-    x_ref = refs[0]
-    q_refs = refs[1:1 + n_cores]
-    s_refs = refs[1 + n_cores:1 + 2 * n_cores]
-    o_ref = refs[1 + 2 * n_cores]
-    xt = x_ref[...]
-    if not shared_x:                       # (1, bt, N) block → (bt, N)
-        xt = xt.reshape(xt.shape[-2], xt.shape[-1])
-    cores = []
-    for k in range(n_cores):
-        q = q_refs[k][...].reshape(-1, block)       # (n_blocks, block)
-        s = s_refs[k][...].reshape(-1, 1)           # (n_blocks, 1) f32
-        deq = q.astype(jnp.float32) * s
-        cores.append(
-            deq.reshape(-1)[:core_sizes[k]].reshape(spec.core_shapes[k]))
-    y = _chain(xt.astype(jnp.float32), cores, spec)
-    o_ref[...] = y.reshape(o_ref.shape).astype(o_ref.dtype)
+def tt_contract(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
+                interpret: bool = False) -> jax.Array:
+    """y = x @ W(cores)^T, fused in VMEM.  x: (..., N) → (..., M)."""
+    stacked = tuple(c[None] for c in cores)
+    y = tt_contract_batched(x.reshape(-1, spec.in_dim), stacked, spec,
+                            interpret=interpret, shared_x=True)
+    return y.reshape(x.shape[:-1] + (spec.out_dim,))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("spec", "quant", "batch_tile",
-                                    "interpret", "shared_x"))
+                   static_argnames=("spec", "quant", "interpret", "shared_x"))
 def tt_contract_batched_quant(x: jax.Array, cores: tuple,
                               spec: tt_lib.TTSpec,
                               quant: quant_lib.QuantConfig,
-                              batch_tile: int | None = None,
                               interpret: bool = False,
                               shared_x: bool | None = None) -> jax.Array:
     """``tt_contract_batched`` with block-scaled int8/fp8-e4m3 cores.
@@ -258,11 +292,11 @@ def tt_contract_batched_quant(x: jax.Array, cores: tuple,
     Each of the P core variants is quantized independently
     (``quantize_blockwise`` per stack row → ``(P, padded)`` narrow codes +
     ``(P, n_blocks)`` f32 scales), shipped to VMEM in the narrow dtype,
-    and dequantized in-kernel before the chain — so HBM weight traffic
-    drops to ~1.125 B/param (block=32) and the math matches
-    ``kernels.ref.tt_contract_batched_quant_ref`` exactly (same
-    quantizer, f32 accumulation in both).  Extra batch axes and the
-    ``shared_x`` flag behave as in ``tt_contract_batched``.
+    and dequantized in-kernel before W is built — so HBM weight traffic
+    is ~1.125 B/param (block=32) and the dequantized cores equal
+    ``kernels.ref.tt_contract_batched_quant_ref``'s bit for bit (same
+    quantizer, same f32 product).  Extra batch axes and the ``shared_x``
+    flag behave as in ``tt_contract_batched``.
     """
     if not quant.weights:
         raise ValueError(f"weight quantization not enabled in {quant}")
@@ -270,40 +304,21 @@ def tt_contract_batched_quant(x: jax.Array, cores: tuple,
         raise ValueError("need at least one core stack")
     P = cores[0].shape[0]
     x, batch_shape, shared_x = _split_batch_axes(x, P, spec, shared_x)
-    B = x.shape[-2]
-    bt = batch_tile or default_batch_tile(spec)
-    bt = min(bt, B)
-    Bp = ((B + bt - 1) // bt) * bt
-    if Bp != B:
-        pad = [(0, 0)] * (x.ndim - 2) + [(0, Bp - B), (0, 0)]
-        x = jnp.pad(x, pad)
 
     quantize = jax.vmap(lambda c: quant_lib.quantize_blockwise(c, quant))
-    qs, ss = [], []
+    qs, ss, spreads = [], [], []
     for c in cores:
         q, s = quantize(c)                 # (P, padded_k), (P, n_blocks_k)
-        qs.append(q)
-        ss.append(s)
-    core_sizes = tuple(int(np.prod(shape)) for shape in spec.core_shapes)
-
-    grid = (P, Bp // bt)
-    if shared_x:
-        in_specs = [pl.BlockSpec((bt, spec.in_dim), lambda p, i: (i, 0))]
-    else:
-        in_specs = [pl.BlockSpec((1, bt, spec.in_dim), lambda p, i: (p, i, 0))]
-    for q in qs:
-        in_specs.append(pl.BlockSpec((1, q.shape[1]), lambda p, i: (p, 0)))
-    for s in ss:
-        in_specs.append(pl.BlockSpec((1, s.shape[1]), lambda p, i: (p, 0)))
-    out_spec = pl.BlockSpec((1, bt, spec.out_dim), lambda p, i: (p, i, 0))
-
-    y = pl.pallas_call(
-        functools.partial(_batched_quant_kernel, spec, spec.L, shared_x,
-                          quant.block, core_sizes),
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=out_spec,
-        out_shape=jax.ShapeDtypeStruct((P, Bp, spec.out_dim), x.dtype),
-        interpret=interpret,
-    )(x, *qs, *ss)
-    return y[:, :B].reshape((P,) + batch_shape + (spec.out_dim,))
+        qs.append(q.reshape(P, 1, -1))
+        ss.append(s.reshape(P, 1, -1))
+        n_blocks, padded = s.shape[1], q.shape[1]
+        spreads.append(jnp.asarray(
+            np.arange(padded)[None] // quant.block
+            == np.arange(n_blocks)[:, None], jnp.float32))
+    flat_lens = tuple(q.shape[-1] for q in qs)
+    core_specs = [_row_spec(q.shape[-1]) for q in qs] \
+        + [_row_spec(s.shape[-1]) for s in ss] \
+        + [pl.BlockSpec(e.shape, lambda p, i: (0, 0)) for e in spreads]
+    y = _launch(x, qs + ss + spreads, core_specs, spec, P, flat_lens,
+                shared_x, True, interpret)
+    return y.reshape((P,) + batch_shape + (spec.out_dim,))

@@ -12,23 +12,26 @@ device state (smoke tests must keep seeing 1 CPU device).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape: tuple, axes: tuple):
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis Auto: GSPMD propagates shardings
+    from the parameter rules, which the model code is written for."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
     """Tiny mesh over whatever devices exist (tests on 1-8 CPU devices)."""
     n = len(jax.devices())
     assert n % model == 0
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return make_mesh((n // model, model), ("data", "model"))
 
 
 # -- hardware constants for the roofline (TPU v5e) --------------------------

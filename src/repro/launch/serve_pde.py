@@ -19,6 +19,7 @@ import json
 import jax
 import numpy as np
 
+from repro.runtime import enable_compile_cache
 from repro.serving import PdeServingEngine, PointRequest, SolverRegistry
 
 
@@ -35,6 +36,7 @@ def main(argv=None):
     ap.add_argument("--cache-capacity", type=int, default=65536)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     reg = SolverRegistry()
     for spec in args.ckpt:

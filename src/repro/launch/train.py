@@ -35,17 +35,19 @@ from pathlib import Path
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
 from repro.checkpoint import CheckpointManager
 from repro.data import DataConfig, synthetic_lm_batch
+from repro.launch.mesh import make_mesh
 from repro.models import api
 from repro.optim import get_optimizer, sign_compress_grads
 from repro.optim.optimizers import default_optimizer_for
 from repro.optim.zo import zo_signsgd_trainer_step
 from repro.parallel import sharding as shd
 from repro.parallel.act import activation_sharding
-from repro.runtime import StragglerWatchdog
+from repro.runtime import StragglerWatchdog, enable_compile_cache
 
 
 def build_train_step(cfg, optimizer, compress_pod_grads: bool = False):
@@ -243,6 +245,7 @@ def train_pinn(args):
                          f"(got --optimizer {opt_name}); the BP baselines "
                          "use the GSPMD mesh path of the LM archs instead")
 
+    one_replica = lambda tree: tree     # params as the forward below sees them
     # both branches share the step signature (params, aux, xt, tb, lr_t) →
     # (params, aux, loss) so one loop below owns watchdog/logging/checkpoints
     # (tb = the per-step term-batch dict from the composite-loss engine)
@@ -271,6 +274,7 @@ def train_pinn(args):
             lambda sp, xt, tb: pinn.residual_losses_stacked(
                 model, sp, xt, hw_noise, term_batches=tb),
             scfg, trainable_mask=mask)
+        one_replica = zo_shard.local_replica
     elif opt_name == "zo-signsgd":
         scfg = zoo.SPSAConfig(num_samples=args.zo_samples, mu=0.01)
         aux = zoo.ZOState.create(args.seed + 1)
@@ -316,6 +320,10 @@ def train_pinn(args):
             print(f"[resume] step {start_step}")
         except FileNotFoundError:
             pass
+    if args.shard:
+        # start replicated on the mesh, where the step leaves them, so the
+        # step compiles once
+        params, aux = jax.device_put((params, aux), NamedSharding(mesh, P()))
 
     # restart-safe counter-based streams (shared data pipeline): the
     # collocation batch on shard 0, the boundary/data term batches on
@@ -337,13 +345,14 @@ def train_pinn(args):
         if step % args.log_every == 0:
             msg = f"step {step} loss {float(loss):.4e} ({st.duration_s:.2f}s)"
             if multi_term:
-                pt = pinn.per_term_losses(model, params, xt, hw_noise,
-                                          term_batches=tb)
+                pt = pinn.per_term_losses(model, one_replica(params), xt,
+                                          hw_noise, term_batches=tb)
                 msg += " [" + " ".join(f"{k}={float(v):.3e}"
                                        for k, v in pt.items()) + "]"
             if val is not None:
-                msg += (" val MSE "
-                        f"{float(pinn.validation_mse(model, params, val, hw_noise)):.4e}")
+                mse = pinn.validation_mse(model, one_replica(params), val,
+                                          hw_noise)
+                msg += f" val MSE {float(mse):.4e}"
             print(msg)
         if mgr and mgr.should_save(step):
             mgr.save(step, {"params": params, aux_name: aux},
@@ -354,8 +363,8 @@ def train_pinn(args):
                  {"step": args.steps, **ckpt_meta})
         mgr.wait()
     if val is not None:
-        print(f"[pinn] final val MSE "
-              f"{float(pinn.validation_mse(model, params, val, hw_noise)):.4e}")
+        mse = pinn.validation_mse(model, one_replica(params), val, hw_noise)
+        print(f"[pinn] final val MSE {float(mse):.4e}")
     print("[train] done")
     return params
 
@@ -447,6 +456,7 @@ def main(argv=None):
                          "(paper Eq. 4's λ — helmholtz-2d's boundary, "
                          "ns-2d's ic); an explicit --term-weight wins")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.arch in PINN_ARCHS:
         return train_pinn(args)
@@ -459,9 +469,9 @@ def main(argv=None):
 
     if args.mesh:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = make_mesh((d, m), ("data", "model"))
     else:
-        mesh = jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+        mesh = make_mesh((len(jax.devices()), 1), ("data", "model"))
 
     opt_name = args.optimizer or default_optimizer_for(args.arch)
     data_cfg = DataConfig(vocab_size=cfg.vocab_size, seq_len=args.seq,

@@ -27,7 +27,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def bubble_fraction(num_stages: int, num_microbatches: int) -> float:
@@ -88,7 +87,7 @@ def pipeline_forward(mesh: Mesh, stage_fn: Callable, stage_params,
         return outputs.reshape(B, *out0.shape[1:])
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
-    fn = shard_map(per_stage, mesh=mesh,
-                   in_specs=(spec_params, P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(per_stage, mesh=mesh,
+                       in_specs=(spec_params, P()), out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x)

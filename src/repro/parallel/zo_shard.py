@@ -69,7 +69,6 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core import zoo
@@ -79,7 +78,7 @@ __all__ = [
     "make_zo_mesh", "pert_shard_size",
     "spsa_gradient_sharded", "zo_signsgd_step_sharded",
     "make_distributed_zo_step", "make_distributed_spsa_gradient",
-    "measure_collective_bytes", "wire_bound_bytes",
+    "local_replica", "measure_collective_bytes", "wire_bound_bytes",
 ]
 
 PyTree = Any
@@ -284,11 +283,11 @@ def make_distributed_zo_step(mesh: Mesh, batched_loss_fn,
         return zo_signsgd_step_sharded(blf, params, state, xt, lr,
                                        cfg, shard_cfg, trainable_mask)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         worker, mesh=mesh,
         in_specs=(P(), P(), P(shard_cfg.batch_axis), P(), P()),
         out_specs=(P(), P(), P()),
-        check_rep=False)
+        check_vma=False)
 
     def step(params, state, xt, bc, lr):
         if xt.shape[0] % shard_cfg.num_batch_shards:
@@ -298,6 +297,15 @@ def make_distributed_zo_step(mesh: Mesh, batched_loss_fn,
         return sharded(params, state, xt, bc, lr)
 
     return jax.jit(step, donate_argnums=(0, 1) if donate else ())
+
+
+def local_replica(tree: PyTree) -> PyTree:
+    """The first local replica of a mesh-replicated pytree, as single-device
+    arrays (no copy).  Code outside the ``shard_map`` (validation, per-term
+    logging) evaluates on it: GSPMD cannot partition a Pallas kernel over
+    the mesh, so a forward on the replicated arrays does not compile on
+    TPU."""
+    return jax.tree.map(lambda a: a.addressable_data(0), tree)
 
 
 def wire_bound_bytes(num_samples: int, n_pert: int, slack: int = 4) -> int:
@@ -319,11 +327,11 @@ def make_distributed_spsa_gradient(mesh: Mesh, batched_loss_fn,
     the gradient-identity tests/benchmarks compare against the single-device
     ``zoo.spsa_gradient`` — same ξ, same layout-invariant result."""
     shard_cfg = ZOShardConfig.from_mesh(mesh)
-    sharded = shard_map(
+    sharded = jax.shard_map(
         lambda p, k, x: spsa_gradient_sharded(batched_loss_fn, p, k, x,
                                               cfg, shard_cfg, trainable_mask),
         mesh=mesh, in_specs=(P(), P(), P(shard_cfg.batch_axis)),
-        out_specs=(P(), P()), check_rep=False)
+        out_specs=(P(), P()), check_vma=False)
     return jax.jit(sharded)
 
 

@@ -9,3 +9,7 @@ import os
 # keep kernel dispatch on the ref path for model-level tests (the Pallas
 # kernels are validated explicitly in tests/test_kernels.py via interpret)
 os.environ.setdefault("REPRO_KERNEL_MODE", "ref")
+
+# tests compile in-process through entry points that turn on JAX's
+# persistent compilation cache; keep the suite hermetic and off the disk
+os.environ.setdefault("JAX_ENABLE_COMPILATION_CACHE", "false")

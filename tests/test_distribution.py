@@ -36,12 +36,13 @@ else:
     from repro.checkpoint import remesh_checkpoint, save_checkpoint, \
         restore_checkpoint
     from repro.core import zoo
+    from repro.launch.mesh import make_mesh
     from repro.models import api
     from repro.parallel import sharding as shd
     from repro.parallel.pipeline import pipeline_forward, bubble_fraction
 
     def _mesh(d, m, names=("data", "model")):
-        return jax.make_mesh((d, m), names)
+        return make_mesh((d, m), names)
 
     def test_param_rules_cover_all_archs():
         mesh = _mesh(4, 2)
@@ -121,7 +122,7 @@ else:
         np.testing.assert_allclose(float(l8), float(l4), rtol=1e-4)
 
     def test_pipeline_forward_matches_sequential():
-        mesh = jax.make_mesh((4, 2), ("pod", "model"))
+        mesh = make_mesh((4, 2), ("pod", "model"))
         P_STAGES, LAYERS_PER = 4, 2
         d = 16
         key = jax.random.PRNGKey(0)
@@ -146,8 +147,7 @@ else:
     def test_distributed_zo_under_shard_map():
         """The scalar-only ZO protocol end-to-end under shard_map over 8
         devices: result must equal the single-host gradient."""
-        from jax.experimental.shard_map import shard_map
-        mesh = jax.make_mesh((8,), ("workers",))
+        mesh = make_mesh((8,), ("workers",))
         target = jnp.asarray(np.random.RandomState(0).randn(16).astype(np.float32))
         loss_fn = lambda p: jnp.sum((p["w"] - target) ** 2)
         params = {"w": jnp.zeros(16)}
@@ -167,8 +167,8 @@ else:
             g = zoo.spsa_gradient_from_losses(params, key, merged, base, cfg)
             return g["w"]
 
-        g = shard_map(worker, mesh=mesh, in_specs=(P("workers"),),
-                      out_specs=P(None), check_rep=False)(
+        g = jax.shard_map(worker, mesh=mesh, in_specs=(P("workers"),),
+                          out_specs=P(None), check_vma=False)(
             jnp.zeros((8, 1)))
         np.testing.assert_allclose(np.asarray(g[0] if g.ndim > 1 else g),
                                    np.asarray(g_ref["w"]), rtol=1e-5)
@@ -279,6 +279,23 @@ else:
         assert traffic["bytes"] <= bound, traffic
         assert traffic["bytes"] < n_param_bytes, \
             f"parameter-sized transfer: {traffic}"
+
+    def test_zo_shard_local_replica_is_one_device():
+        """The step leaves params replicated over the mesh; validation runs
+        on ``local_replica`` — the same values on one device, where a Pallas
+        forward needs no GSPMD partitioning."""
+        model, params, xt, scfg, blf, key = _pinn_setup()
+        mesh = zo_shard.make_zo_mesh("8x1", "perturbation")
+        step = zo_shard.make_distributed_zo_step(
+            mesh, lambda sp, x, bc: blf(sp, x), scfg, donate=False)
+        new, _, _ = step(params, zoo.ZOState.create(0), xt, None, 1e-3)
+        local = zo_shard.local_replica(new)
+        for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(local)):
+            assert len(a.sharding.device_set) == 8
+            assert b.sharding.device_set == {jax.devices()[0]}
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        np.testing.assert_array_equal(
+            np.asarray(model.u(local, xt)), np.asarray(model.u(new, xt)))
 
     def test_zo_shard_elastic_resize_8_to_4(tmp_path):
         """Checkpoint on an 8-device mesh, resume on 4: the loss trajectory

@@ -94,6 +94,25 @@ def test_tt_linear_batched_dispatch_ref_equals_interpret():
                                atol=1e-5, rtol=1e-5)
 
 
+@pytest.mark.parametrize("spec,want", [
+    (tt.PAPER_TONN_SPEC, "interpret"),     # W is 4 MiB
+    (tt.auto_factorize(4096, 4096, L=4, max_rank=8), "ref"),   # W is 64 MiB
+], ids=["paper-tonn", "lm-sized"])
+def test_tt_impl_takes_kernel_only_when_w_fits_vmem(spec, want):
+    assert ops.tt_impl(spec, "interpret") == want
+    assert ops.tt_impl(spec, "ref") == "ref"
+
+
+@pytest.mark.parametrize("ports,want", [
+    (16, "interpret"),                          # a tonn core mesh
+    (ops.MESH_KERNEL_MAX_LEVELS + 4, "ref"),    # an onn-sized mesh
+])
+def test_mesh_impl_takes_kernel_only_for_shallow_meshes(ports, want):
+    lay = photonic.rectangular_layout(ports)
+    assert ops.mesh_impl(lay, "interpret") == want
+    assert ops.mesh_impl(lay, "ref") == "ref"
+
+
 # ------------------------------------------------- mesh_apply_stacked (ZO)
 
 MESH_CASES = [

@@ -155,3 +155,48 @@ def test_bp_elastic_resume_remeshes_params(tmp_path):
     assert info["meta"]["step"] == 2 and isinstance(info["fallbacks"], list)
     np.testing.assert_array_equal(np.asarray(restored["w"]),
                                   np.asarray(params["w"]))
+
+
+# ------------------------------------------------------------ compile cache
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from repro.runtime import enable_compile_cache
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+if {compile}:
+    jax.jit(lambda x: x * 2.0 + 1.0)(jnp.ones(3)).block_until_ready()
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False],
+                         ids=["env-dir", "checkout-dir"])
+def test_compile_cache_directory(tmp_path, from_env):
+    """JAX_COMPILATION_CACHE_DIR, when set, is where the cache goes and
+    nothing in code overrides it; otherwise the cache sits at the fixed
+    git-ignored ``<checkout>/.jax_cache``.  Run in a child process: the
+    cache directory is process-global once a compile has used it."""
+    import os
+    import subprocess
+    import sys
+    from pathlib import Path
+    from repro.runtime.compile_cache import DEFAULT_CACHE_DIR
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.pop("JAX_ENABLE_COMPILATION_CACHE", None)   # the suite turns it off
+    want = DEFAULT_CACHE_DIR
+    if from_env:
+        want = tmp_path / "cache"
+        env["JAX_COMPILATION_CACHE_DIR"] = str(want)
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE.format(compile=from_env)],
+        env=env, capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-2000:]
+    assert r.stdout.split() == [str(want), str(want)]
+    if from_env:
+        assert any(want.iterdir()), "no cache entry written"
+    else:
+        ignored = (DEFAULT_CACHE_DIR.parent / ".gitignore").read_text()
+        assert f"{DEFAULT_CACHE_DIR.name}/" in ignored.split()
