@@ -1,0 +1,9 @@
+"""Share, in %, of the traced window in which no operation runs on the device
+(serving cells)."""
+
+
+def read(ctx):
+    trace = ctx.get("trace")
+    if trace is None or "counters" not in ctx:
+        return None
+    return 100.0 * (1.0 - trace.mean_busy_s() / trace.window_s)
