@@ -26,6 +26,7 @@ Examples (CPU, reduced config):
 from __future__ import annotations
 
 import argparse
+import collections
 import dataclasses
 import json
 import time
@@ -335,14 +336,36 @@ def train_pinn(args):
     terms = pde_term_batch_iterator(max(args.batch // 4, 8), seed=args.seed,
                                     start_step=start_step, problem=problem)
     multi_term = len(problem.loss_terms()) > 1
+    # the watchdog sees the interval between consecutive completed steps:
+    # two steps stay in flight, and the loss of the step two behind is
+    # waited for, so on an accelerator a step's duration is the device's
+    # time per step (or the host's, where the host is the slower), never
+    # the enqueue time of an asynchronous dispatch
+    inflight = collections.deque()      # (step, loss) dispatched, not waited
+    last_done = time.monotonic()
+
+    def complete(done_step, done_loss):
+        nonlocal last_done
+        jax.block_until_ready(done_loss)
+        now = time.monotonic()
+        st = watchdog.end_step(done_step, duration_s=now - last_done)
+        last_done = now
+        return st
+
     for step in range(start_step, args.steps):
         xt = next(colloc)
         tb = next(terms)
-        watchdog.start_step()
         params, aux, loss = step_fn(params, aux, xt, tb,
                                     lr0 * 0.5 ** (step / half_life))
-        st = watchdog.end_step(step)
-        if step % args.log_every == 0:
+        inflight.append((step, loss))
+        if len(inflight) > 2:
+            complete(*inflight.popleft())
+        log_now = step % args.log_every == 0
+        save_now = mgr and mgr.should_save(step)
+        if log_now or save_now:
+            while inflight:             # the log line reads this step's loss
+                st = complete(*inflight.popleft())
+        if log_now:
             msg = f"step {step} loss {float(loss):.4e} ({st.duration_s:.2f}s)"
             if multi_term:
                 pt = pinn.per_term_losses(model, one_replica(params), xt,
@@ -354,9 +377,15 @@ def train_pinn(args):
                                           hw_noise)
                 msg += f" val MSE {float(mse):.4e}"
             print(msg)
-        if mgr and mgr.should_save(step):
+        if save_now:
             mgr.save(step, {"params": params, aux_name: aux},
                      {"step": step, **ckpt_meta})
+        if log_now or save_now:
+            # nothing is in flight: the log line and the checkpoint are
+            # host work of no step's, so the next step's interval starts here
+            last_done = time.monotonic()
+    while inflight:
+        complete(*inflight.popleft())
 
     if mgr:
         mgr.save(args.steps, {"params": params, aux_name: aux},

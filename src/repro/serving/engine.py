@@ -31,6 +31,16 @@ The three invariants the whole design hangs on:
 Repeated stencil/grid queries short-circuit through the ``StencilCache``
 at submit time: cache hits never occupy a slot, and fully-cached requests
 complete without touching a program (``repro.serving.cache``).
+
+Host time is attributed by the engine's ``Recorder``
+(``repro.runtime.trace``): spans ``engine.submit`` (with ``cache.lookup``
+inside), and per step that has work ``engine.step`` with ``engine.pack``
+(admission, grouping, pool buffers), ``engine.launch`` (host-to-device
+copy and the program's asynchronous dispatch), ``engine.fetch`` (the wait
+for the device and the copy back) and ``cache.insert`` inside; scatter
+and slot retirement are ``engine.step``'s self time.  Counters
+``queue_wait_s`` and ``requests_admitted`` sum submit-to-first-slot waits.
+``serving_stats()`` reports all of them.
 """
 
 from __future__ import annotations
@@ -45,6 +55,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime.trace import Recorder
 from repro.serving.cache import StencilCache
 from repro.serving.registry import SolverRegistry
 
@@ -63,8 +74,13 @@ class PointRequest:
     """One client query: evaluate ``u`` of ``solver`` at ``points``.
 
     ``out`` is filled in place (same order as ``points``); ``done`` flips
-    when every point is served.  ``latency_s`` covers submit → completion,
-    including queue wait — the number the benchmark's p50/p99 reports.
+    when every point is served.  Engine timestamps (``time.perf_counter``):
+    ``t_submit`` when ``submit`` accepted the request, ``t_admit`` when a
+    chunk of it first took a slot (``None`` for a request served wholly
+    from the cache), ``t_done`` when its last point was served and put in
+    the cache.  ``latency_s`` is ``t_done − t_submit``, queue wait
+    included; an open loop that times from each request's due time
+    starts earlier.
     ``quant`` (a ``kernels.quant.QuantConfig``) requests quantized
     inference: it extends the program key — one extra AOT program per
     (solver, dtype, quant, slot-shape), compiled once like any other —
@@ -88,6 +104,7 @@ class PointRequest:
     out: np.ndarray | None = None         # (n,) served u-values
     done: bool = False
     t_submit: float = 0.0
+    t_admit: float | None = None
     t_done: float = 0.0
     # internal bookkeeping (engine-owned)
     _miss_idx: np.ndarray | None = None   # positions still to compute
@@ -128,6 +145,7 @@ class PdeServingEngine:
         self.active: list[_Slot | None] = [None] * slots
         self._programs: dict = {}      # (solver, dtype[, quant], S, C) -> exe
         self._fill: dict = {}          # solver -> in-domain fill point
+        self.trace = Recorder()        # host spans and queue-wait counters
         self.stats = {"compiles": 0, "steps": 0, "program_runs": 0,
                       "points_served": 0, "points_padded": 0,
                       "requests_done": 0, "peak_active_slots": 0,
@@ -229,6 +247,10 @@ class PdeServingEngine:
         """Enqueue a request; cache hits are served immediately and only
         the misses ever occupy slots.  Returns the request (its ``out`` /
         ``done`` fields are updated in place as the engine steps)."""
+        with self.trace.span("engine.submit"):
+            return self._submit(req)
+
+    def _submit(self, req: PointRequest) -> PointRequest:
         pts = np.asarray(req.points, np.float64)
         if pts.ndim != 2 or pts.shape[0] == 0:
             raise ValueError(f"points must be (n>0, in_dim), "
@@ -267,9 +289,10 @@ class PdeServingEngine:
         req.t_submit = time.perf_counter()
         req.out = np.empty(pts.shape[0], np.float64)
         if self.cache is not None:
-            keys = self.cache.keys_for(req.solver, req.dtype, pts,
-                                       quant_tag=_quant_tag(req.quant))
-            hit_idx, hit_vals, miss_idx = self.cache.lookup(keys)
+            with self.trace.span("cache.lookup"):
+                keys = self.cache.keys_for(req.solver, req.dtype, pts,
+                                           quant_tag=_quant_tag(req.quant))
+                hit_idx, hit_vals, miss_idx = self.cache.lookup(keys)
             if len(hit_idx):
                 req.out[hit_idx] = hit_vals
             req._miss_idx = miss_idx
@@ -290,10 +313,16 @@ class PdeServingEngine:
     def _admit(self) -> None:
         """Pack head-of-queue chunks into free slots (continuous
         admission): the head request may leave partially packed — its
-        remaining points wait for the next step's free slots."""
+        remaining points wait for the next step's free slots.  A request's
+        first chunk stamps ``t_admit`` and adds its queue wait."""
         free = [s for s in range(self.slots) if self.active[s] is None]
+        now = time.perf_counter()
         while free and self.queue:
             req = self.queue[0]
+            if req._cursor == 0:
+                req.t_admit = now
+                self.trace.add("queue_wait_s", now - req.t_submit)
+                self.trace.add("requests_admitted", 1)
             remaining = len(req._miss_idx) - req._cursor
             count = min(remaining, self.slot_points)
             self.active[free.pop()] = _Slot(req, req._cursor, count)
@@ -302,10 +331,10 @@ class PdeServingEngine:
             if req._cursor >= len(req._miss_idx):
                 self.queue.popleft()
 
-    def step(self) -> int:
-        """One engine step: admit, evaluate every (solver, dtype) group's
-        full-pool program once, scatter results, retire slots.  Returns
-        the number of request points served this step."""
+    def _pack(self) -> list:
+        """Admit, group the occupied slots by (solver, dtype, quant) and
+        fill each group's full-pool input.  Returns ``[(exe, slot_ids,
+        buf)]``, one entry per program run of this step."""
         self._admit()
         groups: dict = {}
         for s, slot in enumerate(self.active):
@@ -314,13 +343,7 @@ class PdeServingEngine:
                     (slot.req.solver, np.dtype(slot.req.dtype).name,
                      _quant_tag(slot.req.quant)),
                     []).append(s)
-        if not groups:
-            return 0
-        self.stats["steps"] += 1
-        self.stats["peak_active_slots"] = max(
-            self.stats["peak_active_slots"],
-            sum(len(v) for v in groups.values()))
-        served = 0
+        runs = []
         for (solver_name, dtype_name, _tag), slot_ids in groups.items():
             dtype = np.dtype(dtype_name)
             quant = self.active[slot_ids[0]].req.quant
@@ -339,33 +362,67 @@ class PdeServingEngine:
                 idx = slot.req._miss_idx[slot.offset:slot.offset
                                          + slot.count]
                 buf[s, :slot.count] = slot.req.points[idx]
-            u = np.asarray(exe(jnp.asarray(
-                buf.reshape(self._pool_shape(width))))).reshape(
-                    self.slots, self.slot_points)
-            self.stats["program_runs"] += 1
-            for s in slot_ids:
-                slot = self.active[s]
-                req = slot.req
-                idx = req._miss_idx[slot.offset:slot.offset + slot.count]
-                vals = u[s, :slot.count]
-                req.out[idx] = vals
+            runs.append((exe, slot_ids,
+                         buf.reshape(self._pool_shape(width))))
+        return runs
+
+    def step(self) -> int:
+        """One engine step: admit, evaluate every (solver, dtype) group's
+        full-pool program once, scatter results, retire slots.  Returns
+        the number of request points served this step (0, and no span,
+        when nothing is queued)."""
+        if not self.queue and all(s is None for s in self.active):
+            return 0
+        with self.trace.span("engine.step"):
+            with self.trace.span("engine.pack"):
+                runs = self._pack()
+            self.stats["steps"] += 1
+            self.stats["peak_active_slots"] = max(
+                self.stats["peak_active_slots"],
+                sum(len(slot_ids) for _, slot_ids, _ in runs))
+            served = 0
+            for exe, slot_ids, buf in runs:
+                with self.trace.span("engine.launch"):
+                    u_dev = exe(jnp.asarray(buf))
+                with self.trace.span("engine.fetch"):
+                    u = np.asarray(u_dev).reshape(self.slots,
+                                                  self.slot_points)
+                self.stats["program_runs"] += 1
+                results = []              # (request, positions, values)
+                finished = []             # requests whose last point is here
+                for s in slot_ids:
+                    slot = self.active[s]
+                    req = slot.req
+                    idx = req._miss_idx[slot.offset:slot.offset
+                                        + slot.count]
+                    vals = u[s, :slot.count]
+                    req.out[idx] = vals
+                    results.append((req, idx, vals))
+                    served += slot.count
+                    self.stats["points_padded"] += \
+                        self.slot_points - slot.count
+                    req._inflight -= 1
+                    if req._inflight == 0 and \
+                            req._cursor >= len(req._miss_idx):
+                        finished.append(req)
+                    self.active[s] = None     # slot recycles next step
                 if self.cache is not None:
-                    self.cache.insert([req._keys[i] for i in idx], vals)
-                served += slot.count
-                self.stats["points_padded"] += self.slot_points - slot.count
-                req._inflight -= 1
-                if req._inflight == 0 and \
-                        req._cursor >= len(req._miss_idx):
-                    req.done = True
-                    req.t_done = time.perf_counter()
-                    self.stats["requests_done"] += 1
-                self.active[s] = None     # slot recycles next step
-            # idle slots of this group's program run are pure padding
-            self.stats["points_padded"] += \
-                (self.slots - len(slot_ids)) * self.slot_points
-        self.stats["points_served"] += served
-        self._sync_cache_stats()
-        return served
+                    with self.trace.span("cache.insert"):
+                        self.cache.insert(
+                            [r._keys[i] for r, idx, _ in results
+                             for i in idx],
+                            np.concatenate([v for _, _, v in results]))
+                # a request is done once its values are in the cache too
+                now = time.perf_counter()
+                for req in finished:
+                    req.done, req.t_done = True, now
+                self.stats["requests_done"] += len(finished)
+                # idle slots of this group's program run are pure padding
+                self.stats["points_padded"] += \
+                    (self.slots - len(slot_ids)) * self.slot_points
+            self.stats["points_served"] += served
+            self._sync_cache_stats()
+            return served
 
     def run(self, max_steps: int | None = None) -> int:
         """Drain the queue: step until nothing is queued or in flight.
@@ -380,8 +437,16 @@ class PdeServingEngine:
 
     # ----------------------------------------------------------- reporting
     def serving_stats(self) -> dict:
+        """``stats`` plus the queue, the program keys, the cache's own
+        stats, the host spans (``spans``: per name ``count``, ``seconds``,
+        ``self_seconds``, ``max_s``) and the queue-wait counters."""
         self._sync_cache_stats()
         out = dict(self.stats)
+        trace = self.trace.summary()
+        out["spans"] = trace["spans"]
+        out["queue_wait_s"] = trace["counters"].get("queue_wait_s", 0.0)
+        out["requests_admitted"] = trace["counters"].get(
+            "requests_admitted", 0)
         out["queued"] = len(self.queue)
         out["programs"] = sorted(
             "|".join(map(str, k)) for k in self._programs)

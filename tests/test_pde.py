@@ -457,6 +457,37 @@ def test_train_cli_pinn_branch_runs_heat(tmp_path):
                 "--log-every", "100"])
 
 
+def test_train_watchdog_leaves_log_line_out_of_next_step(monkeypatch):
+    """The host work of a log step (per-term losses, validation, the
+    print) is no step's time: the watchdog's interval for the step after
+    a log line starts when the line is done."""
+    import time
+
+    from repro.launch import train
+    made = []
+
+    class Recording(train.StragglerWatchdog):
+        def __init__(self, *a, **k):
+            super().__init__(*a, **k)
+            made.append(self)
+
+    def slow_print(*a, **k):
+        if a and str(a[0]).startswith("step "):
+            time.sleep(0.5)
+        print(*a, **k)
+
+    monkeypatch.setattr(train, "StragglerWatchdog", Recording)
+    monkeypatch.setattr(train, "print", slow_print, raising=False)
+    train.main(["--arch", "hjb-pinn", "--pde", "heat-10d", "--reduced",
+                "--steps", "6", "--batch", "8", "--hidden", "16",
+                "--pinn-mode", "tt", "--zo-samples", "3",
+                "--log-every", "3"])
+    durations = {s.step: s.duration_s for s in made[-1].history}
+    assert sorted(durations) == list(range(6))
+    # log lines at steps 0 and 3; steps 1 and 4 complete after them
+    assert durations[1] < 0.5 and durations[4] < 0.5
+
+
 def test_train_cli_pinn_branch_runs_boundary_problem(tmp_path):
     from repro.launch.train import main as train_main
     train_main(["--arch", "tensor-pinn", "--pde", "helmholtz-2d", "--reduced",

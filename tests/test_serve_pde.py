@@ -3,6 +3,8 @@ batching, pad masking, cache hit/eviction, slot recycling under churn,
 compile-once (no recompiles across steps), and checkpoint-metadata loading
 (DESIGN.md §Serving)."""
 
+import json
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -426,6 +428,87 @@ def test_cache_counters_surface_in_engine_stats():
     st = eng.serving_stats()
     assert st["cache_hits"] == st["cache"]["hits"] == 15
     assert st["cache_evictions"] == eng.cache.evictions == 0
+
+
+def test_spans_reconcile_with_engine_stats():
+    """After a mixed run (two solvers, a fully cached, a partly cached and
+    a pool-spanning request), the host spans agree with the counters:
+    one ``engine.step`` per step, one ``engine.launch``/``engine.fetch``
+    per program run, children inside their parent."""
+    reg = _registry([("heat", "heat-10d", "tt"), ("hjb", "hjb-20d", "tt")])
+    eng = PdeServingEngine(reg, slots=3, slot_points=16)
+    heat = _query(reg, "heat", 12, seed=1)
+    reqs = [eng.submit(PointRequest("heat", heat)),
+            eng.submit(PointRequest("hjb", _query(reg, "hjb", 70, seed=2)))]
+    eng.run()
+    reqs.append(eng.submit(PointRequest("heat", heat)))       # all cached
+    reqs.append(eng.submit(PointRequest(
+        "heat", np.concatenate([heat[:4], _query(reg, "heat", 5, seed=3)]))))
+    eng.run()
+    assert all(r.done for r in reqs)
+    st = eng.serving_stats()
+    json.dumps(st)                           # the launcher prints it whole
+    spans = st["spans"]
+    assert spans["engine.step"]["count"] == st["steps"] > 1
+    assert spans["engine.launch"]["count"] == st["program_runs"]
+    assert spans["engine.fetch"]["count"] == st["program_runs"]
+    assert spans["cache.insert"]["count"] == st["program_runs"]
+    assert spans["engine.pack"]["count"] == st["steps"]
+    assert spans["engine.submit"]["count"] == 4
+    assert spans["cache.lookup"]["count"] == 4
+    children = sum(spans[n]["seconds"] for n in (
+        "engine.pack", "engine.launch", "engine.fetch", "cache.insert"))
+    assert children <= spans["engine.step"]["seconds"]
+    assert spans["engine.step"]["self_seconds"] == pytest.approx(
+        spans["engine.step"]["seconds"] - children, abs=1e-9)
+    for span in spans.values():
+        assert 0 <= span["self_seconds"] <= span["seconds"]
+        assert span["max_s"] <= span["seconds"]
+    assert st["requests_admitted"] == 3      # all but the cached one
+    assert st["queue_wait_s"] >= 0
+    for r in (reqs[0], reqs[1], reqs[3]):
+        assert r.t_submit <= r.t_admit <= r.t_done
+
+
+def test_fully_cached_request_adds_no_queue_wait():
+    reg = _registry()
+    eng = PdeServingEngine(reg, slots=2, slot_points=16)
+    pts = _query(reg, "heat", 10, seed=8)
+    eng.submit(PointRequest("heat", pts))
+    eng.run()
+    before = eng.serving_stats()
+    cached = eng.submit(PointRequest("heat", pts))
+    assert cached.done and cached.t_admit is None
+    assert eng.step() == 0                   # nothing queued: no step span
+    after = eng.serving_stats()
+    assert after["requests_admitted"] == before["requests_admitted"] == 1
+    assert after["queue_wait_s"] == before["queue_wait_s"]
+    assert after["spans"]["engine.step"] == before["spans"]["engine.step"]
+
+
+def test_request_done_after_its_values_reach_the_cache():
+    """``t_done`` is stamped after the step's cache insert, so a request's
+    latency covers the insert of its own points."""
+    import time
+
+    reg = _registry()
+    eng = PdeServingEngine(reg, slots=2, slot_points=16)
+    inserted = []                            # perf_counter at each insert's end
+    insert = eng.cache.insert
+
+    def timed_insert(keys, values):
+        insert(keys, values)
+        inserted.append(time.perf_counter())
+
+    eng.cache.insert = timed_insert
+    reqs = [eng.submit(PointRequest("heat", _query(reg, "heat", n, seed=n)))
+            for n in (5, 9, 40)]
+    eng.run()
+    assert all(r.done for r in reqs) and inserted
+    for r in reqs:
+        assert any(t <= r.t_done for t in inserted)
+        last = max(t for t in inserted if t <= r.t_done)
+        assert r.t_done - last < 0.05
 
 
 # ---------------------------------------- coefficient-conditioned serving
