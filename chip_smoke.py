@@ -172,7 +172,8 @@ def kernel_phase(s: Setup, stack: dict):
     from repro.kernels import ops
     for i, spec in enumerate(s.model.specs):
         print(f"[smoke] kernel tt_contract layer {i} {spec.out_modes}x"
-              f"{spec.in_modes} ranks {spec.ranks}: {ops.tt_impl(spec)}")
+              f"{spec.in_modes} ranks {spec.ranks}: {ops.tt_impl(spec)} "
+              f"({ops.tt_path(spec)} body)")
     meshes = {(lay.ports, lay.levels): ops.mesh_impl(lay)
               for pms in s.model.photonic_cores for pm in pms
               for lay in (pm.layout_u, pm.layout_v)}

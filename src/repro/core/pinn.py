@@ -222,19 +222,8 @@ class TensorPinn:
             # interior rank-1 split of the hidden layer (paper ranks
             # [1,2,1,2,1] split at k=2): W1 = W_left ⊗ W_right, enabling the
             # two-GEMM Kronecker head of the stacked ZO path (DESIGN.md §Perf)
-            self._kron_split = self._find_kron_split(self.specs[1])
-
-    @staticmethod
-    def _find_kron_split(spec) -> int | None:
-        """Most balanced interior index k with r_k == 1 (else None)."""
-        best = None
-        for k in range(1, spec.L):
-            if spec.ranks[k] == 1:
-                bal = abs(int(np.prod(spec.in_modes[:k]))
-                          - int(np.prod(spec.in_modes[k:])))
-                if best is None or bal < best[1]:
-                    best = (k, bal)
-        return None if best is None else best[0]
+            from repro.kernels import tt_contract
+            self._kron_split = tt_contract.kron_split(self.specs[1])
 
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> dict:
@@ -518,7 +507,10 @@ class TensorPinn:
         z1 only feeds an elementwise sin and the w2 reduction: we permute
         b1/w2 (1024 floats) instead of the (P, B', 1024) activations.
         On TPU (pallas/interpret dispatch) the stacked contraction kernel
-        already builds W in VMEM, so it is used instead.
+        takes the same split itself (``ops.tt_path`` "kron": ``I ⊗ W_R`` on
+        the MXU, ``W_L`` by lane-rolled VPU multiply-adds, in VMEM and in
+        W's own column order), and specs without an interior rank 1 take
+        its dense-W body; either way the kernel is used instead.
         """
         from repro.kernels import ops
         cfg = self.cfg
@@ -529,12 +521,9 @@ class TensorPinn:
                     and self._kron_split is not None
                     and ops.kernel_mode() == "ref")
         if use_kron:
-            spec = self.specs[1]
+            from repro.kernels import tt_contract
             k = self._kron_split
-            left = tt.TTSpec(spec.out_modes[:k], spec.in_modes[:k],
-                             tuple(spec.ranks[:k + 1]))
-            right = tt.TTSpec(spec.out_modes[k:], spec.in_modes[k:],
-                              tuple(spec.ranks[k:]))
+            left, right = tt_contract.split_spec(self.specs[1], k)
             # same fake-quant the chain path sees, so the Kronecker head
             # stays bit-comparable with the stacked contraction under QAT
             cores = self._fq_cores(list(stacked["cores1"]), stacked=True)

@@ -26,7 +26,7 @@ from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
 from repro.kernels import tt_contract as _ttc
 
-__all__ = ["kernel_mode", "tt_impl", "mesh_impl", "tt_linear",
+__all__ = ["kernel_mode", "tt_impl", "tt_path", "mesh_impl", "tt_linear",
            "tt_linear_batched", "mesh_apply_stacked", "attention",
            "KERNEL_MODES"]
 
@@ -58,6 +58,15 @@ def tt_impl(spec: tt_lib.TTSpec, mode: str | None = None) -> str:
     or "ref" when W and its tables do not fit the kernel's VMEM budget."""
     mode = mode or kernel_mode()
     return mode if mode == "ref" or _ttc.fits_vmem(spec) else "ref"
+
+
+def tt_path(spec: tt_lib.TTSpec, mode: str | None = None) -> str:
+    """Body the TT dispatchers take for ``spec``: "kron" (the kernel
+    contracts through the interior rank-1 split ``W_L ⊗ W_R``), "dense"
+    (the kernel builds W), or "ref" (the jnp chain).  Static per spec."""
+    if tt_impl(spec, mode) == "ref":
+        return "ref"
+    return "kron" if _ttc.kron_factors(spec) else "dense"
 
 
 def mesh_impl(layout, mode: str | None = None) -> str:
@@ -107,7 +116,8 @@ def tt_linear_batched(x: jax.Array, cores: Sequence[jax.Array],
     in pure jnp (the CPU oracle) and pallas/interpret dispatch to the
     narrow-dtype kernel that dequantizes block-scaled cores in VMEM —
     both see bit-identical weights and accumulate f32.  Specs whose dense W
-    does not fit VMEM take the jnp chain (``tt_impl``).
+    does not fit VMEM take the jnp chain (``tt_impl``); ``tt_path`` names
+    the kernel body a spec takes.
     """
     mode = tt_impl(spec, mode)
     if _weight_quant(quant):

@@ -20,6 +20,7 @@ TT_CASES = [
     (1024, 1024, 4, 2, 64),  # the paper's TONN layer
     (256, 512, 4, 8, 7),
     (48, 60, 3, 16, 128),    # rank > unfolding rank (clamped internally)
+    (1024, 1024, 4, 4, 21),  # rank 4, no interior 1: the dense body
 ]
 
 
@@ -81,6 +82,75 @@ def test_tt_contract_batched_matches_stacked_matvec(out_dim, in_dim, L, rank,
         for p in range(P)])
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_loop),
                                atol=1e-5, rtol=1e-5)
+
+
+def _paper_stacks(P):
+    spec = tt.PAPER_TONN_SPEC
+    keys = jax.random.split(jax.random.PRNGKey(0), P)
+    return spec, tuple(jnp.stack([tt.tt_init(k, spec)[i] for k in keys])
+                       for i in range(spec.L))
+
+
+@pytest.mark.parametrize("batch", [37, 64], ids=["unaligned", "aligned"])
+@pytest.mark.parametrize("shared_x", [True, False])
+def test_tt_contract_kron_body_matches_ref_and_stacked_matvec(batch,
+                                                              shared_x):
+    """The paper spec takes the Kronecker body; it matches the jnp oracle
+    and P independent unfused chains, shared and per-P x, and the P = 1
+    entry point matches ``tt_contract_ref``."""
+    from repro.kernels import tt_contract as ttc
+    P = 3
+    spec, stacks = _paper_stacks(P)
+    assert ops.tt_path(spec, "interpret") == "kron"
+    shape = (batch, spec.in_dim) if shared_x else (P, batch, spec.in_dim)
+    x = jax.random.normal(jax.random.PRNGKey(1), shape)
+    y_k = ttc.tt_contract_batched(x, stacks, spec, interpret=True)
+    assert y_k.shape == (P, batch, spec.out_dim)
+    y_ref = ref.tt_contract_batched_ref(x, stacks, spec)
+    y_loop = jnp.stack([
+        tt.tt_matvec([s[p] for s in stacks], x if shared_x else x[p], spec)
+        for p in range(P)])
+    for want in (y_ref, y_loop):
+        np.testing.assert_allclose(np.asarray(y_k), np.asarray(want),
+                                   atol=1e-5, rtol=1e-5)
+    cores = [s[1] for s in stacks]
+    x1 = x if shared_x else x[1]
+    np.testing.assert_allclose(
+        np.asarray(ops.tt_linear(x1, cores, spec, mode="interpret")),
+        np.asarray(ref.tt_contract_ref(x1, cores, spec)),
+        atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("shared_x", [True, False])
+def test_tt_contract_kron_rows_do_not_depend_on_batch(shared_x):
+    """Rows [:k] of a k-row call equal the same rows of a longer call, bit
+    for bit: served-vs-direct and stack-slice parity rest on it."""
+    from repro.kernels import tt_contract as ttc
+    P = 2
+    spec, stacks = _paper_stacks(P)
+    long, k = 40, 5     # a looped chunk and a tail, against a tail alone
+    shape = (long, spec.in_dim) if shared_x else (P, long, spec.in_dim)
+    x = jax.random.normal(jax.random.PRNGKey(3), shape)
+    full = np.asarray(ttc.tt_contract_batched(x, stacks, spec,
+                                              interpret=True))
+    xk = x[:k] if shared_x else x[:, :k]
+    part = np.asarray(ttc.tt_contract_batched(xk, stacks, spec,
+                                              interpret=True))
+    np.testing.assert_array_equal(part, full[:, :k])
+
+
+@pytest.mark.parametrize("spec,mode,want", [
+    (tt.PAPER_TONN_SPEC, "interpret", "kron"),
+    (tt.PAPER_TONN_SPEC, "pallas", "kron"),
+    (tt.PAPER_TONN_SPEC, "ref", "ref"),
+    (tt.auto_factorize(1024, 1024, L=4, max_rank=4), "interpret", "dense"),
+    # an interior rank 1, but 64 columns do not tile a 128-lane vreg
+    (tt.TTSpec((8, 8), (8, 8), (1, 1, 1)), "interpret", "dense"),
+    (tt.auto_factorize(4096, 4096, L=4, max_rank=8), "interpret", "ref"),
+], ids=["paper-interpret", "paper-pallas", "paper-ref", "rank4-1024",
+        "untiled-split", "lm-sized"])
+def test_tt_path_picks_body_from_spec(spec, mode, want):
+    assert ops.tt_path(spec, mode) == want
 
 
 def test_tt_linear_batched_dispatch_ref_equals_interpret():

@@ -125,6 +125,23 @@ def test_quant_kernel_matches_fake_quant_oracle(qcfg, shared_x):
 
 
 @pytest.mark.parametrize("qcfg", QCFGS, ids=lambda q: q.dtype)
+def test_quant_kernel_kron_body_matches_fake_quant_oracle(qcfg):
+    """The paper spec dequantizes, then takes the Kronecker body."""
+    spec = tt.PAPER_TONN_SPEC
+    assert ops.tt_path(spec, "interpret") == "kron"
+    P, B = 3, 21
+    keys = jax.random.split(jax.random.PRNGKey(4), P)
+    stacks = tuple(jnp.stack([tt.tt_init(k, spec)[i] for k in keys])
+                   for i in range(spec.L))
+    x = jax.random.normal(jax.random.PRNGKey(5), (P, B, spec.in_dim))
+    y_ref = ref.tt_contract_batched_quant_ref(x, stacks, spec, qcfg)
+    y_k = ttc.tt_contract_batched_quant(x, stacks, spec, qcfg,
+                                        interpret=True)
+    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_ref),
+                               atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("qcfg", QCFGS, ids=lambda q: q.dtype)
 def test_ops_dispatch_quant_ref_equals_interpret(qcfg):
     """ops.tt_linear[_batched] with quant: the ref (fake-quant jnp) and
     interpret (narrow-dtype kernel) dispatch arms agree."""

@@ -1,10 +1,11 @@
 """Compile-only checks of the PINN Pallas kernels for a described TPU v5e.
 
 Nothing runs: each test lowers a kernel at the paper's width (two TT layers
-of ``PAPER_TONN_SPEC``, P = N+1 = 11 perturbations, 43 × 100 stencil rows,
-the serving pool of 8 × 256 points, the tonn core meshes) and compiles it
-with the TPU compiler for one chip of a described ``v5e:2x2`` topology.
-The compiler refuses what interpret mode accepts (block shapes off the
+of ``PAPER_TONN_SPEC``, which take the Kronecker body, and a spec without
+an interior rank 1 that keeps the dense one; P = N+1 = 11 perturbations,
+43 × 100 stencil rows, the serving pool of 8 × 256 points, the tonn core
+meshes) and compiles it with the TPU compiler for one chip of a described
+``v5e:2x2`` topology.  The compiler refuses what interpret mode accepts (block shapes off the
 (8, 128) tiling, unsupported relayouts), so these guard every kernel edit
 at no chip time.  The topology is described inside a fixture, so no worker
 loads the TPU library while collecting.
@@ -75,6 +76,27 @@ def test_tt_contract_batched_zo_step_compiles(shape, shared_x):
               else (P, STENCIL_ROWS, SPEC.in_dim))
     _assert_kernel(
         lambda x, *c: tt_contract.tt_contract_batched(x, c, SPEC), x, *cores)
+
+
+@pytest.mark.parametrize("rows", [21, 100], ids=["columns", "layer0"])
+def test_tt_contract_batched_small_tiles_compile(shape, rows):
+    """Layer 0's calls of a step: the 21 stencil columns and the 100-row
+    batch, shared across P; tiles below and off the VPU stage's chunk."""
+    cores = [shape((P,) + s) for s in SPEC.core_shapes]
+    _assert_kernel(
+        lambda x, *c: tt_contract.tt_contract_batched(x, c, SPEC),
+        shape((rows, SPEC.in_dim)), *cores)
+
+
+def test_tt_contract_dense_body_compiles(shape):
+    """A spec of the paper's width with ranks (1, 2, 2, 2, 1) has no
+    interior rank 1 and keeps the dense-W body."""
+    spec = tt.auto_factorize(1024, 1024, L=4, max_rank=2)
+    assert tt_contract.kron_factors(spec) is None
+    cores = [shape((P,) + s) for s in spec.core_shapes]
+    _assert_kernel(
+        lambda x, *c: tt_contract.tt_contract_batched(x, c, spec),
+        shape((P, STENCIL_ROWS, spec.in_dim)), *cores)
 
 
 def test_tt_contract_batched_quant_int8_compiles(shape):
