@@ -7,8 +7,8 @@ Arms per ZO-step row (N=10 SPSA samples unless overridden):
 
   * ``stacked``        — this PR: ONE batched gather-form mesh pass
     densifies all N+1 perturbed TONN core meshes
-    (``PhotonicMatrix.to_dense_stacked``), onn's layer matvecs run through
-    ``apply_stacked``, and the fixed ±1 diag buffers are excluded from the
+    (``PhotonicMatrix.to_dense_stacked``), onn's layer meshes densify the
+    same way before dense products, and the fixed ±1 diag buffers are excluded from the
     SPSA probe (``TensorPinn.trainable_mask``).
   * ``vmap_fallback``  — the generic ``residual_losses_stacked`` fallback
     (``jax.vmap`` of the scalar loss — the ONLY pre-PR path for onn),
@@ -169,8 +169,7 @@ def bench_zo_mode(mode: str, hidden: int, batch: int, num_samples: int,
     sp = jax.tree.map(lambda p, z: p + scfg.mu * z, params, xis)
     h = model.fd_step
     prepared = model.prepare_params_stacked(sp, noise)
-    eff_noise = noise if mode == "onn" else None
-    u_stacked = model.fd_u_stencil_stacked(prepared, xt, h, eff_noise)
+    u_stacked = model.fd_u_stencil_stacked(prepared, xt, h)
     seq_stencil = jax.jit(lambda p: model.fd_u_stencil(p, xt, h, noise))
     with scan_mesh():  # sequential reference = the scan-mesh realism path
         jax.block_until_ready(

@@ -174,12 +174,13 @@ def kernel_phase(s: Setup, stack: dict):
         print(f"[smoke] kernel tt_contract layer {i} {spec.out_modes}x"
               f"{spec.in_modes} ranks {spec.ranks}: {ops.tt_impl(spec)} "
               f"({ops.tt_path(spec)} body)")
-    meshes = {(lay.ports, lay.levels): ops.mesh_impl(lay)
+    meshes = {(lay.ports, lay.levels): (ops.mesh_impl(lay),
+                                        ops.mesh_path(lay))
               for pms in s.model.photonic_cores for pm in pms
               for lay in (pm.layout_u, pm.layout_v)}
-    for (ports, levels), impl in sorted(meshes.items()):
+    for (ports, levels), (impl, path) in sorted(meshes.items()):
         print(f"[smoke] kernel mesh_apply_stacked ports {ports} levels "
-              f"{levels}: {impl}")
+              f"{levels}: {impl} ({path} body)")
     t0 = time.perf_counter()
     compiled = jax.jit(s.batched_loss_fn()).lower(stack, s.xt).compile()
     found = compiled_kernels(compiled.as_text())

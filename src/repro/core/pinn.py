@@ -192,18 +192,26 @@ class TensorPinn:
         self._quant = cfg.quant if cfg.quant.enabled else None
         # stacked hot path: vectorized polynomial sine (XLA:CPU's jnp.sin is
         # a scalar libm call); ~2 ulp, within the FD noise floor (DESIGN.md
-        # §Perf).  The sequential photonic-realism path keeps libm sin.
-        self._sin = fastmath.fast_sin if cfg.use_fused_kernel else jnp.sin
+        # §Perf).  The sequential photonic-realism path keeps libm sin, and
+        # so does onn: its network part of u is O(1) (tonn's ≈ 5e-5 of u),
+        # so ~2 ulp of sine reach u's last bit, which the stencil's 1/h²
+        # amplifies (cost on a TPU v5e: ≈ 2.5 ms of a 38.9 ms hjb-20d step).
+        self._sin = (fastmath.fast_sin
+                     if cfg.use_fused_kernel and cfg.mode != "onn"
+                     else jnp.sin)
         h = cfg.hidden
-        if cfg.mode in ("tt", "tonn"):
+        if cfg.mode in ("tt", "tonn", "onn"):
             # pad the input up to a TT-factorizable width (the paper folds
-            # 21 → 1024 so layer 1 is a 1024×1024 TT matrix); coefficient
-            # slots (and feature-map outputs) count toward the unpadded width
+            # 21 → 1024 so layer 1 is a 1024×1024 TT matrix, or in onn mode
+            # a pair of 1024-port meshes); coefficient slots (and
+            # feature-map outputs) count toward the unpadded width
             self.in_pad = h if h >= self.feat_in else -(-self.feat_in // 8) * 8
         else:
             self.in_pad = self.feat_in
         # layer dims after padding the input up to the TT-factorizable size
         self.dims = [(h, self.in_pad), (h, h), (1, h)]
+        # the TT layers' specs; dense and onn layers are plain matrices
+        self.specs = []
         if cfg.mode in ("tt", "tonn"):
             self.specs = [
                 tt.hjb_layer_spec(h, self.in_pad, L=cfg.tt_L, max_rank=cfg.tt_rank),
@@ -323,16 +331,61 @@ class TensorPinn:
             cores.append(w.reshape(shape + spec.core_shapes[k]))
         return cores
 
+    def _densify_onn(self, params: dict, noise: dict | None,
+                     stacked: bool = False) -> dict:
+        """ONN layers 0 and 1: each ``W = U Σ Vᵀ`` densified through its
+        meshes on the identity feed, with the hardware noise applied to the
+        phases first (``stacked=True`` densifies a leading SPSA axis in ONE
+        batched mesh pass, ``PhotonicMatrix.apply_stacked``).  The feed's
+        rows come out as W's columns, kept as ``wt{i} = Wᵀ`` (in × out).
+        Layer 0's input is zero-padded from ``feat_in`` to its mesh's
+        ``in_pad`` ports, so only its first ``feat_in`` columns are ever
+        read, and only those are densified.  The layers then multiply by
+        ``wt{i}``: densifying a 1024-port layer pushes at most 1,024
+        columns through its meshes, where applying them to the stencil's
+        rows would push 4,300 (DESIGN.md §Photonic).
+
+        The phases and noise pass an ``optimization_barrier`` first: where
+        they are constants of a ``jit`` (a serving program, a closure),
+        XLA would otherwise fold their cos/sin at compile time with a sine
+        of its own, ≈ 1 ulp from the compiled one, and the answers would
+        follow whether the weights were closed over."""
+        cfg = self.cfg
+        eff = {k: v for k, v in params.items() if k not in ("p0", "p1")}
+        phases, noise = jax.lax.optimization_barrier(
+            ({k: params[k] for k in ("p0", "p1")}, noise))
+        for i, pm in enumerate(self.photonic):
+            nz = None if noise is None else noise[f"p{i}"]
+            apply = pm.apply_stacked if stacked else pm.apply
+            rows = self.feat_in if i == 0 else pm.in_dim
+            eff[f"wt{i}"] = apply(phases[f"p{i}"],
+                                  jnp.eye(rows, pm.in_dim, dtype=jnp.float32),
+                                  cfg.noise if nz else None, nz,
+                                  quant=self._quant)
+        return eff
+
+    def _dense_layer(self, params: dict, i: int) -> jax.Array:
+        """Layer i of a dense or onn model as (..., in, out): ``w{i}``
+        transposed, or the densified ``wt{i}`` (onn layer 0: its first
+        ``feat_in`` rows, those the zero-padded input reads)."""
+        if self.cfg.mode == "dense":
+            return jnp.swapaxes(params[f"w{i}"], -1, -2)
+        return params[f"wt{i}"]
+
     def prepare_params(self, params: dict, noise: dict | None) -> tuple:
-        """Hoist TONN densification: pcores → dense TT-cores ONCE per loss
-        evaluation (the seed re-densified per ``_layer_matvec`` call, i.e.
-        per FD stencil × per SPSA perturbation — DESIGN.md §Perf).
+        """Hoist densification: TONN pcores → dense TT-cores, ONN meshes →
+        dense layer matrices, ONCE per loss evaluation (the seed
+        re-densified per ``_layer_matvec`` call, i.e. per FD stencil × per
+        SPSA perturbation — DESIGN.md §Perf).
 
         Returns ``(effective_params, effective_noise)``; a no-op for modes
-        whose forward consumes ``params`` directly (dense / onn / tt) and
-        for already-prepared dicts.
+        whose forward consumes ``params`` directly (dense / tt) and for
+        already-prepared dicts.
         """
-        if self.cfg.mode != "tonn" or "cores0" in params:
+        mode = self.cfg.mode
+        if mode == "onn" and "p0" in params:
+            return self._densify_onn(params, noise), None
+        if mode != "tonn" or "cores0" in params:
             return params, noise
         eff = {k: v for k, v in params.items() if not k.startswith("pcores")}
         for i in range(len(self.specs)):
@@ -355,14 +408,11 @@ class TensorPinn:
 
     def _layer_matvec(self, params: dict, noise: dict | None, i: int,
                       x: jax.Array) -> jax.Array:
+        """Layer-i matvec of one (prepared) parameter set."""
         cfg = self.cfg
-        if cfg.mode == "dense":
-            return jnp.matmul(x, params[f"w{i}"].T, precision=_HIGHEST)
-        if cfg.mode == "onn":
-            pm = self.photonic[i]
-            nz = None if noise is None else noise[f"p{i}"]
-            return pm.apply(params[f"p{i}"], x, cfg.noise if nz else None,
-                            nz, quant=self._quant)
+        if cfg.mode in ("dense", "onn"):
+            w = self._dense_layer(params, i)
+            return jnp.matmul(x[..., :w.shape[-2]], w, precision=_HIGHEST)
         spec = self.specs[i]
         cores = params.get(f"cores{i}")
         if cores is None:  # unprepared tonn params: densify on the fly
@@ -454,13 +504,18 @@ class TensorPinn:
     # --------------------------------------- stacked (multi-perturbation) ZO
     def prepare_params_stacked(self, stacked: dict, noise: dict | None) -> dict:
         """``prepare_params`` over a leading perturbation axis P on every
-        leaf: every TONN core mesh densifies all N+1 SPSA-perturbed phase
-        sets in ONE batched pass (``PhotonicMatrix.to_dense_stacked`` —
-        the batched mesh engine, sharing the identity feed and the layout
-        across the stack; hardware noise is shared too — one physical
-        chip).  The seed vmapped the scalar ``prepare_params`` instead,
-        re-tracing the scatter-per-level mesh scan per perturbation."""
-        if self.cfg.mode != "tonn" or "cores0" in stacked:
+        leaf: every TONN core mesh and ONN layer mesh densifies all N+1
+        SPSA-perturbed phase sets in ONE batched pass
+        (``PhotonicMatrix.to_dense_stacked`` — the batched mesh engine,
+        sharing the identity feed and the layout across the stack;
+        hardware noise is shared too — one physical chip, and is baked
+        into the result).  The seed vmapped the scalar ``prepare_params``
+        instead, re-tracing the scatter-per-level mesh scan per
+        perturbation."""
+        mode = self.cfg.mode
+        if mode == "onn" and "p0" in stacked:
+            return self._densify_onn(stacked, noise, stacked=True)
+        if mode != "tonn" or "cores0" in stacked:
             return stacked
         eff = {k: v for k, v in stacked.items() if not k.startswith("pcores")}
         for i in range(len(self.specs)):
@@ -468,24 +523,22 @@ class TensorPinn:
                                                    stacked=True)
         return eff
 
-    def _layer_matvec_stacked(self, stacked: dict, i: int, x: jax.Array,
-                              noise: dict | None = None) -> jax.Array:
-        """Layer-i matvec for P stacked parameter sets.  x: (B', n) shared
-        across the stack or (P, B', n) per-entry; returns (P, B', m).
-        ``noise`` is only consulted in ``onn`` mode (TONN bakes the
-        hardware noise into the densified cores)."""
+    def _layer_matvec_stacked(self, stacked: dict, i: int,
+                              x: jax.Array) -> jax.Array:
+        """Layer-i matvec for P stacked (prepared) parameter sets.  x:
+        (B', n) shared across the stack or (P, B', n) per-entry; returns
+        (P, B', m)."""
         cfg = self.cfg
-        if cfg.mode == "dense":
-            w = stacked[f"w{i}"]
-            if x.ndim == 2:   # per-entry GEMMs, as in tt.tt_matvec_stacked
+        if cfg.mode in ("dense", "onn"):
+            w = self._dense_layer(stacked, i)                 # (P, in, out)
+            x = x[..., :w.shape[-2]]
+            if x.ndim == 2:
                 x = jnp.broadcast_to(x, (w.shape[0],) + x.shape)
-            return jnp.einsum("pbn,pmn->pbm", x, w, precision=_HIGHEST)
-        if cfg.mode == "onn":
-            pm = self.photonic[i]
-            nz = None if noise is None else noise[f"p{i}"]
-            return pm.apply_stacked(stacked[f"p{i}"], x,
-                                    cfg.noise if nz else None, nz,
-                                    quant=self._quant)
+            # one entry at a time, each the sequential layer's product: XLA
+            # on TPU may tile a stacked product by P, so entry p's rounding
+            # would follow the stack size (DESIGN.md §Distributed)
+            return jax.lax.map(lambda e: jnp.matmul(
+                e[0], e[1], precision=_HIGHEST), (x, w))
         spec = self.specs[i]
         cores = stacked[f"cores{i}"]
         if cfg.use_fused_kernel:
@@ -494,8 +547,7 @@ class TensorPinn:
         return tt.tt_matvec_stacked(self._fq_cores(cores, stacked=True),
                                     x, spec)
 
-    def _f_head_stacked(self, stacked: dict, a: jax.Array,
-                        noise: dict | None = None) -> jax.Array:
+    def _f_head_stacked(self, stacked: dict, a: jax.Array) -> jax.Array:
         """``f = sin(W1·a + b1) @ w2ᵀ + b2`` for P stacked parameter sets:
         (P, B', hidden) activations → (P, B') f-values.
 
@@ -547,7 +599,7 @@ class TensorPinn:
             w2p = stacked["w2"].reshape(P, ML, MR) \
                 .transpose(0, 2, 1).reshape(P, 1, cfg.hidden)
         else:
-            z = self._layer_matvec_stacked(stacked, 1, a, noise)
+            z = self._layer_matvec_stacked(stacked, 1, a)
             b1p, w2p = stacked["b1"], stacked["w2"]
         if ops.kernel_mode() == "pallas":
             # XLA on TPU tiles a stacked reduction's output by P, so entry
@@ -560,46 +612,42 @@ class TensorPinn:
         return (f + stacked["b2"][:, None])[..., 0]
 
     def fd_u_stencil_stacked(self, stacked: dict, xt: jax.Array,
-                             h: float, noise: dict | None = None) -> jax.Array:
+                             h: float) -> jax.Array:
         """``fd_u_stencil`` for P stacked (prepared) parameter sets in one
         batched program: (P, 2·Din+1, B) u-values.  The collocation stencil
         is shared across the stack, so layer 1 reads x once per batch tile
         regardless of P (the fused-kernel analogue of TONN's one optical
         pass over all perturbed meshes); the problem ansatz broadcasts over
-        the leading P axis.  In ``onn`` mode the layer matvecs run through
-        the batched mesh engine (``PhotonicMatrix.apply_stacked``) with the
-        shared hardware ``noise``."""
+        the leading P axis."""
         cfg = self.cfg
         B = xt.shape[0]
         A = self.in_dim
         P = stacked["b0"].shape[0]
         xp = self._embed(xt)
-        z0 = self._layer_matvec_stacked(stacked, 0, xp, noise) \
+        z0 = self._layer_matvec_stacked(stacked, 0, xp) \
             + stacked["b0"][:, None]                                  # (P,B,H)
         eye = jnp.eye(self.in_dim, self.in_pad, dtype=jnp.float32)
-        cols = self._layer_matvec_stacked(stacked, 0, eye, noise)     # (P,A,H)
+        cols = self._layer_matvec_stacked(stacked, 0, eye)            # (P,A,H)
         hcols = h * cols
         z = jnp.concatenate(
             [z0[:, None],
              z0[:, None] + hcols[:, :, None],                         # +h e_i
              z0[:, None] - hcols[:, :, None]], axis=1)         # (P,2A+1,B,H)
         a = self._sin(z).reshape(P, (2 * A + 1) * B, cfg.hidden)
-        f = self._f_head_stacked(stacked, a, noise).reshape(P, 2 * A + 1, B)
+        f = self._f_head_stacked(stacked, a).reshape(P, 2 * A + 1, B)
         return self.problem.ansatz(f, pde_lib.fd_stencil_points(xt, h, A))
 
-    def f_stacked(self, stacked: dict, xt: jax.Array,
-                  noise: dict | None = None) -> jax.Array:
+    def f_stacked(self, stacked: dict, xt: jax.Array) -> jax.Array:
         """Base network for P stacked (prepared) parameter sets over a
         SHARED input batch: (B, net_in) → (P, B)."""
         h = self._embed(xt)
-        a = self._sin(self._layer_matvec_stacked(stacked, 0, h, noise)
+        a = self._sin(self._layer_matvec_stacked(stacked, 0, h)
                       + stacked["b0"][:, None])
-        return self._f_head_stacked(stacked, a, noise)
+        return self._f_head_stacked(stacked, a)
 
-    def u_stacked(self, stacked: dict, xt: jax.Array,
-                  noise: dict | None = None) -> jax.Array:
+    def u_stacked(self, stacked: dict, xt: jax.Array) -> jax.Array:
         """Ansatz u for P stacked parameter sets: (B, net_in) → (P, B)."""
-        return self.problem.ansatz(self.f_stacked(stacked, xt, noise), xt)
+        return self.problem.ansatz(self.f_stacked(stacked, xt), xt)
 
     # ------------------------------------------- coefficient-family queries
     def _coeff_rows(self, pts: jax.Array, coeffs: jax.Array) -> jax.Array:
@@ -627,13 +675,12 @@ class TensorPinn:
                       noise).reshape(C, B)
 
     def u_coeff_grid_stacked(self, stacked: dict, pts: jax.Array,
-                             coeffs: jax.Array,
-                             noise: dict | None = None) -> jax.Array:
+                             coeffs: jax.Array) -> jax.Array:
         """``u_coeff_grid`` for P stacked parameter sets: (P, C, B) — the
         perturbations × coefficients double batch of the conditioned ZO
         path, flattened through the stacked evaluator."""
         C, B = coeffs.shape[0], pts.shape[0]
-        vals = self.u_stacked(stacked, self._coeff_rows(pts, coeffs), noise)
+        vals = self.u_stacked(stacked, self._coeff_rows(pts, coeffs))
         return vals.reshape(vals.shape[0], C, B)
 
 
@@ -812,9 +859,8 @@ def residual_losses_stacked(model: TensorPinn, stacked_params: dict,
 
     For dense/tt/tonn/onn with FD or spectral derivatives this runs as a
     small number of batched programs (densify-once via the batched mesh
-    engine, stacked TT contraction via ``tt_linear_batched``, stacked mesh
-    matvecs via ``PhotonicMatrix.apply_stacked`` in onn mode, one shared
-    stencil — or one shared set of spectral line rows, FFT'd per
+    engine, stacked TT contraction via ``tt_linear_batched``, dense layer
+    products in dense and onn mode, one shared stencil — or one shared set of spectral line rows, FFT'd per
     perturbation after the single stacked forward).  Other mode/estimator
     combinations (Stein derivatives) fall back to a vmap of the scalar
     loss — correct everywhere, fused where it matters.  The fallback
@@ -838,24 +884,23 @@ def residual_losses_stacked(model: TensorPinn, stacked_params: dict,
             lambda p, k: residual_loss(model, p, xt, noise, k, bc,
                                        term_batches)
         )(stacked_params, keys)
+    # the photonic modes bake the (shared-chip) hardware noise into the
+    # densified cores / layer matrices
     prepared = model.prepare_params_stacked(stacked_params, noise)
-    # tonn bakes the (shared-chip) hardware noise into the densified cores;
-    # onn applies it in the stacked mesh matvecs
-    eff_noise = noise if cfg.mode == "onn" else None
     if deriv == "spectral":
         M, extent, _ = _spectral_grid(model)
         rows = spectral_lib.spectral_line_rows(xt, model.in_dim, M, extent)
-        vals = model.u_stacked(prepared, rows, eff_noise)     # (P, R)
+        vals = model.u_stacked(prepared, rows)     # (P, R)
         losses = _spectral_loss_terms(model, vals, rows, xt)  # (P,)
     else:
         h = model.fd_step
         if deriv == "fd_fast":
-            vals = model.fd_u_stencil_stacked(prepared, xt, h, eff_noise)
+            vals = model.fd_u_stencil_stacked(prepared, xt, h)
         else:
             B, D = xt.shape
             A = model.in_dim  # coefficient slots are never differentiated
             pts = pde_lib.fd_stencil_points(xt, h, A)
-            vals = model.u_stacked(prepared, pts.reshape(-1, D), eff_noise)
+            vals = model.u_stacked(prepared, pts.reshape(-1, D))
             vals = vals.reshape(vals.shape[0], 2 * A + 1, B)
         losses = jax.vmap(
             lambda v: _loss_from_u_stencil(problem, v, h, xt))(vals)
@@ -864,7 +909,7 @@ def residual_losses_stacked(model: TensorPinn, stacked_params: dict,
         losses = coll_w * losses
     for t, (xb, ub) in plan:
         losses = losses + t.weight * _boundary_mse(
-            model.u_stacked(prepared, xb, eff_noise), ub)
+            model.u_stacked(prepared, xb), ub)
     return losses
 
 

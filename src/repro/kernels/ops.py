@@ -26,19 +26,19 @@ from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
 from repro.kernels import tt_contract as _ttc
 
-__all__ = ["kernel_mode", "tt_impl", "tt_path", "mesh_impl", "tt_linear",
-           "tt_linear_batched", "mesh_apply_stacked", "attention",
-           "KERNEL_MODES"]
+__all__ = ["kernel_mode", "tt_impl", "tt_path", "mesh_impl", "mesh_path",
+           "tt_linear", "tt_linear_batched", "mesh_apply_stacked",
+           "attention", "KERNEL_MODES"]
 
 KERNEL_MODES = ("pallas", "interpret", "ref")
 
-# above this many mesh levels the fully-unrolled kernel chain stops being
-# worth compiling (onn-sized meshes: levels == ports, e.g. hidden 1024) —
-# the jnp gather path takes over regardless of mode
+# the one-hot body unrolls its level chain: past this many levels it stops
+# being worth compiling (onn-sized meshes: levels == ports, e.g. 1024)
 MESH_KERNEL_MAX_LEVELS = 128
 # the one-hot permutation stack (levels × P × P f32) must leave VMEM room
 # for the batch tile; past this footprint the grid would degrade to tiny
-# tiles re-streaming the table from HBM, so the jnp path wins instead
+# tiles re-streaming the table from HBM.  Deeper or wider rectangular
+# meshes take the ``mesh_rect`` body; other layouts the jnp path.
 MESH_KERNEL_MAX_ONEHOT_BYTES = 2 * 2**20
 
 
@@ -69,14 +69,29 @@ def tt_path(spec: tt_lib.TTSpec, mode: str | None = None) -> str:
     return "kron" if _ttc.kron_factors(spec) else "dense"
 
 
-def mesh_impl(layout, mode: str | None = None) -> str:
-    """Implementation ``mesh_apply_stacked`` takes for ``layout``: the
-    kernel mode, or "ref" for deep or wide meshes."""
-    mode = mode or kernel_mode()
-    fits = (layout.levels <= MESH_KERNEL_MAX_LEVELS
+def _onehot_fits(layout) -> bool:
+    return (layout.levels <= MESH_KERNEL_MAX_LEVELS
             and 4 * layout.levels * layout.ports * layout.ports
             <= MESH_KERNEL_MAX_ONEHOT_BYTES)
+
+
+def mesh_impl(layout, mode: str | None = None) -> str:
+    """Implementation ``mesh_apply_stacked`` takes for ``layout``: the
+    kernel mode, or "ref" for deep or wide meshes that are not
+    rectangular."""
+    mode = mode or kernel_mode()
+    fits = _onehot_fits(layout) or _mesh.is_rectangular(layout)
     return mode if fits else "ref"
+
+
+def mesh_path(layout, mode: str | None = None) -> str:
+    """Body ``mesh_apply_stacked`` takes for ``layout``: "onehot" (levels
+    unrolled, one-hot permutation matmuls), "rect" (deep rectangular
+    meshes: wire pairs by lane rolls, levels looped), or "ref" (the jnp
+    gather path).  Static per layout."""
+    if mesh_impl(layout, mode) == "ref":
+        return "ref"
+    return "onehot" if _onehot_fits(layout) else "rect"
 
 
 def _weight_quant(quant) -> bool:
@@ -143,11 +158,11 @@ def mesh_apply_stacked(layout, phases: jax.Array, diag: jax.Array,
 
     phases ``(S, levels, slots)`` (one set per SPSA perturbation), diag
     ``(P,)`` shared buffer or ``(S, P)``, x ``(B, P)`` shared or
-    ``(S, B, P)``; returns ``(S, B, P)``.  Dispatches between the Pallas
-    kernel (grid over stack × batch tiles, level chain looped in-kernel)
-    and the jnp gather reference (``photonic.mesh_apply_stacked``); deep or
-    wide meshes (levels > MESH_KERNEL_MAX_LEVELS, or a one-hot permutation
-    table past MESH_KERNEL_MAX_ONEHOT_BYTES) always take the jnp path.
+    ``(S, B, P)``; returns ``(S, B, P)``.  Dispatches by ``mesh_path``
+    between the two Pallas bodies (one-hot for shallow meshes; ``mesh_rect``
+    for deep or wide rectangular ones) and the jnp gather reference
+    (``photonic.mesh_apply_stacked``), which deep or wide meshes of any
+    other layout always take.
 
     ``quant`` with ``phase_bits`` set snaps the commanded phases to the
     uniform DAC grid before EITHER backend runs — the quantization is a
@@ -156,14 +171,15 @@ def mesh_apply_stacked(layout, phases: jax.Array, diag: jax.Array,
     ``PhotonicMatrix`` quantize before the noise model instead and pass
     quant=None here — idempotence makes the double hook safe anyway.)
     """
-    mode = mesh_impl(layout, mode)
+    path = mesh_path(layout, mode)
     if quant is not None and quant.phases:
         phases = _quant.quantize_phases(phases, quant.phase_bits)
-    if mode == "ref":
+    if path == "ref":
         return _ph.mesh_apply_stacked(layout, phases, diag, x, transpose)
-    return _mesh.mesh_apply_stacked_pallas(layout, phases, diag, x,
-                                           transpose=transpose,
-                                           interpret=(mode == "interpret"))
+    body = (_mesh.mesh_apply_stacked_pallas if path == "onehot"
+            else _mesh.mesh_apply_rect_pallas)
+    return body(layout, phases, diag, x, transpose=transpose,
+                interpret=(mesh_impl(layout, mode) == "interpret"))
 
 
 def attention(q: jax.Array, k: jax.Array, v: jax.Array,

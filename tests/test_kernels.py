@@ -173,14 +173,27 @@ def test_tt_impl_takes_kernel_only_when_w_fits_vmem(spec, want):
     assert ops.tt_impl(spec, "ref") == "ref"
 
 
-@pytest.mark.parametrize("ports,want", [
-    (16, "interpret"),                          # a tonn core mesh
-    (ops.MESH_KERNEL_MAX_LEVELS + 4, "ref"),    # an onn-sized mesh
-])
-def test_mesh_impl_takes_kernel_only_for_shallow_meshes(ports, want):
-    lay = photonic.rectangular_layout(ports)
-    assert ops.mesh_impl(lay, "interpret") == want
-    assert ops.mesh_impl(lay, "ref") == "ref"
+def _deep_qr_layout(ports: int = 40):
+    """A Givens-QR (Reck) layout: 2·ports − 3 levels, not rectangular."""
+    u = np.linalg.qr(np.random.RandomState(3).randn(ports, ports))[0]
+    return photonic.decompose_orthogonal(u)
+
+
+@pytest.mark.parametrize("layout,want,path", [
+    (photonic.rectangular_layout(16), "interpret", "onehot"),   # tonn core
+    # an onn-sized rectangular mesh: too deep to unroll, rolled instead
+    (photonic.rectangular_layout(ops.MESH_KERNEL_MAX_LEVELS + 4),
+     "interpret", "rect"),
+    # a deep mesh of another layout: the jnp path
+    (_deep_qr_layout(ops.MESH_KERNEL_MAX_LEVELS // 2 + 8)[0], "ref", "ref"),
+], ids=["tonn-core", "onn-rect", "deep-qr"])
+def test_mesh_impl_takes_kernel_only_for_shallow_meshes(layout, want, path):
+    """The kernel takes shallow meshes (one-hot body) and deep rectangular
+    ones (``mesh_rect``); other deep layouts take the jnp path."""
+    assert ops.mesh_impl(layout, "interpret") == want
+    assert ops.mesh_path(layout, "interpret") == path
+    assert ops.mesh_impl(layout, "ref") == "ref"
+    assert ops.mesh_path(layout, "ref") == "ref"
 
 
 # ------------------------------------------------- mesh_apply_stacked (ZO)
@@ -227,18 +240,54 @@ def test_mesh_apply_stacked_kernel_qr_layout_and_stacked_diag():
 
 
 def test_mesh_apply_stacked_deep_mesh_falls_back_to_ref():
-    """Levels above MESH_KERNEL_MAX_LEVELS (onn-sized meshes) must silently
-    take the jnp path in every mode — no unrollable kernel is built."""
-    ports = ops.MESH_KERNEL_MAX_LEVELS + 4
-    lay = photonic.rectangular_layout(ports)
+    """Levels above MESH_KERNEL_MAX_LEVELS in a layout that is not
+    rectangular must silently take the jnp path in every mode — no
+    unrollable kernel is built."""
+    lay, ph, d = _deep_qr_layout(ops.MESH_KERNEL_MAX_LEVELS // 2 + 8)
+    ports = lay.ports
     assert lay.levels > ops.MESH_KERNEL_MAX_LEVELS
-    phs = 0.1 * jax.random.normal(jax.random.PRNGKey(0),
-                                  (2,) + lay.phase_shape())
-    d = jnp.ones((ports,))
+    phs = jnp.stack([ph, 0.9 * ph])
     x = jax.random.normal(jax.random.PRNGKey(1), (4, ports))
     y_i = ops.mesh_apply_stacked(lay, phs, d, x, mode="interpret")
     y_r = ops.mesh_apply_stacked(lay, phs, d, x, mode="ref")
     np.testing.assert_array_equal(np.asarray(y_i), np.asarray(y_r))
+
+
+RECT_CASES = [
+    # (ports, S, batch, shared_x, transpose)
+    (64, 3, 64, True, True),      # densify: Vᵀ on a shared identity feed
+    (64, 3, 21, False, False),    # U on per-entry rows, off the row chunk
+    (32, 11, 40, True, False),    # N=10 SPSA stack + base, shared rows
+    (33, 2, 9, False, True),      # odd ports: padded wires, odd level count
+]
+
+
+@pytest.mark.parametrize("ports,S,batch,shared_x,transpose", RECT_CASES)
+def test_mesh_rect_kernel_matches_ref(ports, S, batch, shared_x, transpose):
+    """The deep-mesh body, forced at a small width in interpret mode,
+    against the jnp gather path: the same per-level arithmetic in the same
+    order, so f32-identical; and entry s of the stack is the unstacked
+    apply of entry s."""
+    from repro.kernels import mesh_apply
+    lay = photonic.rectangular_layout(ports)
+    assert mesh_apply.is_rectangular(lay)
+    key = jax.random.PRNGKey(ports)
+    phs = jax.random.normal(key, (S,) + lay.phase_shape())
+    d = jnp.sign(jax.random.normal(jax.random.fold_in(key, 1), (ports,)))
+    d = jnp.where(d == 0, 1.0, d)
+    shape = (batch, ports) if shared_x else (S, batch, ports)
+    x = jax.random.normal(jax.random.fold_in(key, 2), shape)
+    y_ref = photonic.mesh_apply_stacked(lay, phs, d, x, transpose=transpose)
+    y_k = mesh_apply.mesh_apply_rect_pallas(lay, phs, d, x,
+                                            transpose=transpose,
+                                            interpret=True)
+    assert y_k.shape == (S, batch, ports)
+    np.testing.assert_array_equal(np.asarray(y_k), np.asarray(y_ref))
+    s = S - 1
+    y_s = mesh_apply.mesh_apply_rect_pallas(
+        lay, phs[s:], d, x if shared_x else x[s:], transpose=transpose,
+        interpret=True)
+    np.testing.assert_array_equal(np.asarray(y_s[0]), np.asarray(y_k[s]))
 
 
 # ------------------------------------------------------------ flash attention

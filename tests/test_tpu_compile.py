@@ -4,7 +4,7 @@ Nothing runs: each test lowers a kernel at the paper's width (two TT layers
 of ``PAPER_TONN_SPEC``, which take the Kronecker body, and a spec without
 an interior rank 1 that keeps the dense one; P = N+1 = 11 perturbations,
 43 × 100 stencil rows, the serving pool of 8 × 256 points, the tonn core
-meshes) and compiles it with the TPU compiler for one chip of a described
+meshes, the onn layers' 1024-port meshes) and compiles it with the TPU compiler for one chip of a described
 ``v5e:2x2`` topology.  The compiler refuses what interpret mode accepts (block shapes off the
 (8, 128) tiling, unsupported relayouts), so these guard every kernel edit
 at no chip time.  The topology is described inside a fixture, so no worker
@@ -118,6 +118,51 @@ def test_mesh_apply_stacked_tonn_mesh_compiles(shape, ports, shared_x):
         lambda ph, d, x: mesh_apply.mesh_apply_stacked_pallas(
             layout, ph, d, x, transpose=shared_x),
         shape((P,) + layout.phase_shape()), shape((ports,)), x)
+
+
+# the onn layers' 1024-port meshes: layer 1's Vᵀ on the shared identity
+# feed and its U on the per-entry result; layer 0's Vᵀ and U on the 21
+# columns that its zero-padded input reads
+@pytest.mark.parametrize("rows,shared_x", [(1024, True), (1024, False),
+                                           (21, True), (21, False)],
+                         ids=["identity-feed", "per-s-x",
+                              "layer0-identity-feed", "layer0-columns"])
+def test_mesh_rect_onn_mesh_compiles(shape, rows, shared_x):
+    layout = photonic.rectangular_layout(1024)
+    x = shape((rows, 1024) if shared_x else (P, rows, 1024))
+    _assert_kernel(
+        lambda ph, d, x: mesh_apply.mesh_apply_rect_pallas(
+            layout, ph, d, x, transpose=shared_x),
+        shape((P,) + layout.phase_shape()), shape((1024,)), x)
+
+
+@pytest.mark.parametrize("n_stack", [3, P])
+def test_stacked_onn_layer_rows_do_not_depend_on_stack_size(
+        one_chip, n_stack, monkeypatch):
+    """The onn layers multiply by their densified W one entry at a time on
+    TPU, so no product or reduction of the stacked forward carries the
+    stack axis (DESIGN.md §Distributed); the meshes densify in the
+    ``mesh_rect`` kernel."""
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    model = pinn.TensorPinn(pinn_config("hjb-20d", "onn", noise=True))
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    stacked = jax.tree.map(sds, jax.eval_shape(lambda: jax.tree.map(
+        lambda a: jnp.stack([a] * n_stack),
+        model.init(jax.random.PRNGKey(0)))))
+    noise = jax.tree.map(sds, jax.eval_shape(
+        lambda: model.sample_noise(jax.random.PRNGKey(1))))
+    rows = jax.ShapeDtypeStruct((100, model.problem.net_dim), jnp.float32,
+                                sharding=one_chip)
+    text = jax.jit(lambda sp, nz, x: model.f_stacked(
+        model.prepare_params_stacked(sp, nz), x)).lower(
+            stacked, noise, rows).compile().as_text()
+    kernels = set(re.findall(r"%([A-Za-z_]\w*?)(?:\.\d+)? = [^\n]*"
+                             r"custom_call_target=\"tpu_custom_call\"", text))
+    assert kernels == {"mesh_rect"}, kernels
+    outs = re.findall(r"= f32\[([\d,]*)\]\{[^}]*\} "
+                      r"(?:reduce|dot|convolution)\(", text)
+    assert [o for o in outs if o.startswith("100,")], outs   # per entry
+    assert not [o for o in outs if o.startswith(f"{n_stack},")], outs
 
 
 @pytest.mark.parametrize("n_stack", [3, P])
