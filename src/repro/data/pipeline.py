@@ -18,7 +18,9 @@ softmax see realistic skew rather than uniform noise.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
+from functools import partial
 from typing import Iterator
 
 import jax
@@ -64,14 +66,97 @@ def lm_batch_iterator(cfg: DataConfig, start_step: int = 0,
         step += 1
 
 
+# A block's batches stay in device memory until the iterator has yielded
+# them, so the block is kept to a few MiB: a large batch gets a short block
+# (one batch a call from 2 MiB a batch on) and the memory stays the batch's.
+MAX_BLOCK_STEPS = 64
+BLOCK_BYTES = 4 << 20
+
+
+def block_steps(batch_bytes: int) -> int:
+    """K: the largest power of two ≤ ``MAX_BLOCK_STEPS`` whose K batches of
+    ``batch_bytes`` fit in ``BLOCK_BYTES``; at least 1."""
+    k = MAX_BLOCK_STEPS
+    while k > 1 and k * batch_bytes > BLOCK_BYTES:
+        k //= 2
+    return k
+
+
+class CounterStream:
+    """Counter-keyed batches, drawn K steps at a time: step s yields
+    ``sample(_step_key(seed, s, shard))``, from ``start_step`` on.
+
+    A block of K steps takes three calls: a jitted one derives the K keys
+    and advances the step counter it keeps on the device (no transfer), the
+    sampler runs once over all K keys (``jax.vmap``), and a jitted one
+    splits the result into the K batches the iterator yields in turn.  The
+    sampler runs op by op, as the per-step form runs it, and not under
+    ``jit``: in one program XLA:CPU fuses its multiply-adds into FMAs,
+    which would move the conditioned families' coefficients and ns-2d's
+    observations by a rounding, and the stream stays bit-identical to the
+    per-step form.
+    K follows the bytes of one batch (``block_steps``).  Blocks start at
+    ``start_step``, and a batch depends on its key alone, so a stream
+    resumed at step k replays the batches ≥ k exactly.  A sampler whose
+    batch holds no arrays (a problem with no non-collocation terms) is
+    never called: the stream yields its empty structure and draws nothing.
+
+    ``device_calls`` counts the blocks drawn, ``batches`` the batches
+    yielded.
+    """
+
+    def __init__(self, sample, seed: int, start_step: int = 0,
+                 shard: int = 0):
+        key_shape = jax.eval_shape(jax.random.PRNGKey, 0)
+        leaves, self._treedef = jax.tree.flatten(
+            jax.eval_shape(sample, key_shape))
+        self.block = block_steps(sum(x.size * x.dtype.itemsize
+                                     for x in leaves))
+        self.device_calls = 0
+        self.batches = 0
+        self._ready = collections.deque()
+        self._sample = sample
+        if leaves:
+            self._keys = jax.jit(partial(_block_keys, k=self.block,
+                                         shard=shard))
+            self._unstack = jax.jit(partial(_unstack, k=self.block))
+            self._base = jax.random.PRNGKey(seed)
+            self._step = jnp.asarray(start_step, jnp.int32)
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        self.batches += 1
+        if self._treedef.num_leaves == 0:
+            return jax.tree.unflatten(self._treedef, [])
+        if not self._ready:
+            self._step, keys = self._keys(self._base, self._step)
+            self._ready.extend(self._unstack(jax.vmap(self._sample)(keys)))
+            self.device_calls += 1
+        return self._ready.popleft()
+
+
+def _block_keys(base, step, *, k: int, shard: int):
+    """(step + k, the keys of steps step … step + k − 1 on ``shard``)."""
+    steps = step + jnp.arange(k, dtype=step.dtype)
+    return step + k, jax.vmap(lambda s: jax.random.fold_in(
+        jax.random.fold_in(base, s), shard))(steps)
+
+
+def _unstack(out, *, k: int) -> tuple:
+    return tuple(jax.tree.map(lambda a: a[j], out) for j in range(k))
+
+
 def pde_collocation_iterator(n: int, space_dim: int = 20, seed: int = 0,
                              start_step: int = 0,
                              pde: str | None = None,
                              problem=None,
                              coeffs_per_step: int | None = None
-                             ) -> Iterator[jax.Array]:
-    """Counter-based collocation stream.  ``pde`` selects a registered
-    problem's own domain sampler (``repro.pde``); an explicit ``problem``
+                             ) -> CounterStream:
+    """Counter-based collocation stream (a ``CounterStream`` on shard 0).
+    ``pde`` selects a registered problem's own domain sampler
+    (``repro.pde``); an explicit ``problem``
     instance overrides the registry lookup (how the trainer threads
     ``--coeff-range`` rebuilt specs through); the default keeps the
     legacy HJB-domain behavior parameterized by ``space_dim``.
@@ -111,15 +196,12 @@ def pde_collocation_iterator(n: int, space_dim: int = 20, seed: int = 0,
             sample = lambda key: problem.sample_collocation(key, n)
     else:
         sample = lambda key: pinn_lib.sample_collocation(key, n, space_dim)
-    step = start_step
-    while True:
-        yield sample(_step_key(seed, step))
-        step += 1
+    return CounterStream(sample, seed, start_step)
 
 
 def pde_term_batch_iterator(n: int, seed: int = 0, start_step: int = 0,
                             pde: str | None = None, problem=None,
-                            sizes: dict | None = None) -> Iterator[dict]:
+                            sizes: dict | None = None) -> CounterStream:
     """Counter-based stream of NON-collocation term batches: yields one
     ``{term_name: (x, target)}`` dict per step — the ``term_batches=``
     form ``repro.core.pinn.residual_loss`` consumes — covering every
@@ -134,8 +216,8 @@ def pde_term_batch_iterator(n: int, seed: int = 0, start_step: int = 0,
 
     ``n`` is the default batch size per term; ``sizes`` overrides it per
     name (``{"data": 256}``).  Terms whose sampler returns None are
-    skipped that step; a problem with no non-collocation terms yields
-    empty dicts.
+    skipped; a problem with no non-collocation terms yields empty dicts
+    and draws nothing on the device.
     """
     if problem is None:
         from repro import pde as pde_lib
@@ -143,17 +225,17 @@ def pde_term_batch_iterator(n: int, seed: int = 0, start_step: int = 0,
     sizes = sizes or {}
     terms = [(i, t) for i, t in enumerate(problem.loss_terms())
              if t.kind != "collocation" and t.sample is not None]
-    step = start_step
-    while True:
-        key = _step_key(seed, step, shard=1)
+
+    def sample(key):
         out = {}
         for i, t in terms:
             batch = t.sample(jax.random.fold_in(key, i),
                              int(sizes.get(t.name, n)))
             if batch is not None:
                 out[t.name] = batch
-        yield out
-        step += 1
+        return out
+
+    return CounterStream(sample, seed, start_step, shard=1)
 
 
 def pde_line_grid_iterator(n_anchors: int, seed: int = 0,
