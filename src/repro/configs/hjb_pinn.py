@@ -2,8 +2,7 @@
 parameterized over the ``repro.pde`` registry (PINNConfig rather than
 ModelConfig — this is the photonic side).  The Table-1 rows below bind the
 paper's 20-dim HJB benchmark; ``pinn_config``/``pinn_reduced`` build the
-same model for any registered PDE (``--pde`` in ``repro.launch.train`` and
-``benchmarks/pde_suite.py``)."""
+same model for any registered PDE (``--pde`` in ``repro.launch.train``)."""
 import dataclasses
 
 from repro.core.pinn import PINNConfig

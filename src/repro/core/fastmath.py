@@ -1,14 +1,14 @@
 """Vectorizable transcendentals for the fused ZO hot path.
 
-XLA:CPU lowers ``jnp.sin`` to a scalar libm call per element (~50 M elem/s
-on 2 cores — measured in DESIGN.md §Perf), which makes the sine activation
-a dominant cost of the stacked multi-perturbation PINN sweep.  ``fast_sin``
-is the classic Cephes-style argument-reduction + degree-7 minimax
-polynomial, built from mul/add/select primitives that XLA vectorizes and
-fuses into neighbouring elementwise work.  Max error ≈ 2 ulp of float32
-over |x| ≲ 1e4 — within the FD-stencil noise floor documented in DESIGN.md
-§Perf.  Selected by ``PINNConfig.use_fused_kernel``; the sequential
-photonic-realism path keeps libm ``jnp.sin``.
+XLA:CPU lowers ``jnp.sin`` to a scalar libm call per element, which makes
+the sine activation a dominant cost of the stacked multi-perturbation PINN
+sweep on the CPU.  ``fast_sin`` is the classic Cephes-style argument-
+reduction + degree-7 minimax polynomial, built from mul/add/select
+primitives that XLA vectorizes and fuses into neighbouring elementwise
+work.  Max error ≈ 2 ulp of float32 over |x| ≲ 1e4 — within the FD-stencil
+noise floor documented in DESIGN.md §Perf.  Selected by
+``PINNConfig.use_fused_kernel``; the sequential photonic-realism path keeps
+libm ``jnp.sin``.
 """
 
 from __future__ import annotations

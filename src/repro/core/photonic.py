@@ -360,8 +360,7 @@ def mesh_apply_scan(layout: MeshLayout, phases: jax.Array, diag: jax.Array,
     sequential photonic-realism reference: one rotation column at a time,
     exactly like light traversing the physical mesh.  The gather form
     (``mesh_apply``) applies the same arithmetic and agrees to f32
-    rounding; parity is asserted in tests/test_photonic_stacked.py and
-    benchmarks/photonic_mesh.py."""
+    rounding; parity is asserted in tests/test_photonic_stacked.py."""
     P = layout.ports
     batch_shape = x.shape[:-1]
     xf = x.reshape(-1, P)

@@ -185,7 +185,6 @@ class TensorPinn:
         # explicitly-passed fd_step equal to it was silently replaced.)
         self.fd_step = (cfg.fd_step if cfg.fd_step is not None
                         else self.problem.fd_step)
-        self._kron_split: int | None = None
         # quantization hooks take None when disabled so every consumer
         # early-returns to the exact unquantized code path (the f32
         # off-path invariant, DESIGN.md §Quantization)
@@ -226,12 +225,6 @@ class TensorPinn:
                  in spec.core_shapes]
                 for spec in self.specs
             ]
-        if cfg.mode in ("tt", "tonn"):
-            # interior rank-1 split of the hidden layer (paper ranks
-            # [1,2,1,2,1] split at k=2): W1 = W_left ⊗ W_right, enabling the
-            # two-GEMM Kronecker head of the stacked ZO path (DESIGN.md §Perf)
-            from repro.kernels import tt_contract
-            self._kron_split = tt_contract.kron_split(self.specs[1])
 
     # ------------------------------------------------------------------ init
     def init(self, key: jax.Array) -> dict:
@@ -549,66 +542,21 @@ class TensorPinn:
 
     def _f_head_stacked(self, stacked: dict, a: jax.Array) -> jax.Array:
         """``f = sin(W1·a + b1) @ w2ᵀ + b2`` for P stacked parameter sets:
-        (P, B', hidden) activations → (P, B') f-values.
-
-        CPU fast path: when the hidden layer's TT ranks contain an interior
-        1 (the paper's [1,2,1,2,1] does, at k=2) the layer decouples into a
-        Kronecker product W1 = W_L ⊗ W_R of two small dense factors, so the
-        matvec is two trailing-dim batched GEMMs with NO relayout passes —
-        the output lands column-PERMUTED, which is free to absorb because
-        z1 only feeds an elementwise sin and the w2 reduction: we permute
-        b1/w2 (1024 floats) instead of the (P, B', 1024) activations.
-        On TPU (pallas/interpret dispatch) the stacked contraction kernel
-        takes the same split itself (``ops.tt_path`` "kron": ``I ⊗ W_R`` on
-        the MXU, ``W_L`` by lane-rolled VPU multiply-adds, in VMEM and in
-        W's own column order), and specs without an interior rank 1 take
-        its dense-W body; either way the kernel is used instead.
-        """
+        (P, B', hidden) activations → (P, B') f-values.  The hidden layer
+        goes through ``_layer_matvec_stacked``: on TPU the stacked
+        contraction kernel takes the paper spec's Kronecker split itself
+        (``ops.tt_path`` "kron")."""
         from repro.kernels import ops
-        cfg = self.cfg
-        P, Bp, _ = a.shape
-        # Kronecker head is part of the fused hot path only: the unfused
-        # stacked sweep stays bit-comparable with the sequential one
-        use_kron = (cfg.use_fused_kernel and cfg.mode in ("tt", "tonn")
-                    and self._kron_split is not None
-                    and ops.kernel_mode() == "ref")
-        if use_kron:
-            from repro.kernels import tt_contract
-            k = self._kron_split
-            left, right = tt_contract.split_spec(self.specs[1], k)
-            # same fake-quant the chain path sees, so the Kronecker head
-            # stays bit-comparable with the stacked contraction under QAT
-            cores = self._fq_cores(list(stacked["cores1"]), stacked=True)
-            wl = jax.vmap(lambda cs: tt.tt_to_full(cs, left))(
-                list(cores[:k]))                         # (P, ML, NL)
-            wr = jax.vmap(lambda cs: tt.tt_to_full(cs, right))(
-                list(cores[k:]))                         # (P, MR, NR)
-            ML, NL = left.out_dim, left.in_dim
-            MR, NR = right.out_dim, right.in_dim
-            x = a.reshape(P, Bp * NL, NR)
-            x = jax.lax.dot_general(x, wr, (((2,), (2,)), ((0,), (0,))),
-                                    precision=_HIGHEST,
-                                    preferred_element_type=jnp.float32)
-            x = x.reshape(P, Bp, NL, MR)
-            z = jax.lax.dot_general(x, wl, (((2,), (2,)), ((0,), (0,))),
-                                    precision=_HIGHEST,
-                                    preferred_element_type=jnp.float32)
-            z = z.reshape(P, Bp, cfg.hidden)   # column index = i_R·ML + i_L
-            b1p = stacked["b1"].reshape(P, ML, MR) \
-                .transpose(0, 2, 1).reshape(P, cfg.hidden)
-            w2p = stacked["w2"].reshape(P, ML, MR) \
-                .transpose(0, 2, 1).reshape(P, 1, cfg.hidden)
-        else:
-            z = self._layer_matvec_stacked(stacked, 1, a)
-            b1p, w2p = stacked["b1"], stacked["w2"]
+        z = self._layer_matvec_stacked(stacked, 1, a)
+        b1, w2 = stacked["b1"], stacked["w2"]
         if ops.kernel_mode() == "pallas":
             # XLA on TPU tiles a stacked reduction's output by P, so entry
             # p's rounding would follow the stack size (DESIGN.md
             # §Distributed); a loop body's shapes do not depend on P
             f = jax.lax.map(lambda e: _out_head(self._sin(e[0] + e[1]), e[2]),
-                            (z, b1p, w2p))
+                            (z, b1, w2))
         else:
-            f = _out_head(self._sin(z + b1p[:, None]), w2p)
+            f = _out_head(self._sin(z + b1[:, None]), w2)
         return (f + stacked["b2"][:, None])[..., 0]
 
     def fd_u_stencil_stacked(self, stacked: dict, xt: jax.Array,

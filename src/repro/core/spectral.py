@@ -17,7 +17,8 @@ at the anchor and the residual is evaluated there.  Inference bill per
 loss evaluation: ``B·(A·(M−1) + 1)`` distinct rows (the anchor row is
 shared by its A lines) vs FD's ``B·(2A+1)`` — with exact derivatives a
 much smaller anchor batch carries the same training signal, which is where
-the ≥3× inference cut comes from (BENCH_residual_perf.json).
+the ≥3× inference cut comes from (702 vs 2300 rows a loss on the 10-dim
+workloads, tests/test_spectral.py).
 
 Domain periodization (``periodization=``):
 

@@ -218,8 +218,8 @@ def tt_matvec_stacked(cores: Sequence[jax.Array], x: jax.Array,
     Deliberately a vmap of ``tt_matvec`` — the per-entry computation graph
     is identical to the sequential chain, so stacked and serial ZO sweeps
     agree bitwise (the FD residual squares second differences, amplifying
-    any f32 reassociation by 1/h²; see DESIGN.md §Perf).  The *fast* CPU
-    hidden-layer path is the Kronecker head in ``HJBPinn._f_head_stacked``.
+    any f32 reassociation by 1/h²; see DESIGN.md §Perf).  On TPU the
+    stacked contraction kernel (``kernels/tt_contract.py``) takes its place.
     """
     if x.ndim == 2:
         # a shared x is broadcast, not closed over: every entry then runs

@@ -4,7 +4,8 @@ drive the slot-batched inference runtime (``repro.serving``).
 Each ``--ckpt NAME=DIR`` loads a self-describing ``launch/train.py``
 checkpoint into the registry; ``--synthetic N`` generates N mixed
 variable-size requests against every loaded solver (a traffic smoke /
-sizing tool — the measured benchmark is ``benchmarks/serve_pde.py``).
+sizing tool — the measured benchmark is the chip cell
+``tonn-hjb20d.serve-80``, ``bench/run.py``).
 
     PYTHONPATH=src python -m repro.launch.serve_pde \
         --ckpt heat=ckpts/heat-10d --ckpt hjb=ckpts/hjb-20d \

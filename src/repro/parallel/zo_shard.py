@@ -28,8 +28,8 @@ model size.  Parameters, perturbations, and gradients never cross a device
 boundary: every device regenerates the ξ stack from the shared step key and
 contracts the psum-merged loss deltas against it locally
 (``zoo.spsa_gradient_from_losses``).  ``measure_collective_bytes`` verifies
-this from the compiled HLO — benchmarks/distributed_zo.py asserts the
-measured bytes-on-wire against the O(N)-scalar bound in CI.
+this from the compiled HLO — tests/test_distribution.py asserts the
+measured bytes-on-wire against the O(N)-scalar bound.
 
 Gradient-identity contract: for a fixed ``(params, key, xt)``, the gradient
 returned by ``make_distributed_zo_step`` is identical across ALL mesh
@@ -312,8 +312,7 @@ def wire_bound_bytes(num_samples: int, n_pert: int, slack: int = 4) -> int:
     """The O(N)-scalar per-device traffic budget of one distributed step:
     the psum of the zero-padded (N+1)-vector plus the pmean of the local
     slice, all f32, plus a few scalars of slack.  The single home of the
-    bound that tests and benchmarks assert ``measure_collective_bytes``
-    against."""
+    bound that tests assert ``measure_collective_bytes`` against."""
     per = pert_shard_size(num_samples + 1, n_pert)
     return 4 * (per * n_pert + per + slack)
 
@@ -324,7 +323,7 @@ def make_distributed_spsa_gradient(mesh: Mesh, batched_loss_fn,
                                    ) -> Callable:
     """Gradient-only counterpart of ``make_distributed_zo_step``: a jitted
     ``(params, key, xt) -> (grad, base_loss)`` over the mesh.  This is what
-    the gradient-identity tests/benchmarks compare against the single-device
+    the gradient-identity tests compare against the single-device
     ``zoo.spsa_gradient`` — same ξ, same layout-invariant result."""
     shard_cfg = ZOShardConfig.from_mesh(mesh)
     sharded = jax.shard_map(

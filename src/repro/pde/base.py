@@ -572,7 +572,7 @@ def estimate_for_problem(problem: PDEProblem, f: Callable, xt: jax.Array,
     """Derivative estimate of a callable u at rows ``xt`` under the
     problem's DECLARED estimator (or an explicit override), with the
     domain Jacobian folded in — the single dispatch the registry smoke
-    test, benchmarks and ad-hoc validation share, so "evaluate the
+    test and ad-hoc validation share, so "evaluate the
     residual the way this problem is trained" is one call.
 
     ``f(rows) -> values`` must accept arbitrarily-shaped leading axes

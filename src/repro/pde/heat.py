@@ -21,7 +21,7 @@ condition is exact for every κ, so the training loss is the residual alone.
 
 Conditioning (``kappa_range`` set): rows gain a trailing κ slot sampled
 per point; the fixed ``kappa`` argument instead pins a single diffusivity
-(the dedicated-checkpoint arms of ``benchmarks/coeff_family.py``).
+(a dedicated fixed-coefficient checkpoint).
 """
 
 from __future__ import annotations

@@ -8,8 +8,8 @@ MSE against the exact solution is the ground-truth test that conditioning
 actually works (not just that a residual went down).
 
 Training here is the off-chip BP baseline (AdamW) purely for test budget —
-the conditioned input contract is identical for the ZO paths, which
-``benchmarks/coeff_family.py`` exercises at paper scale.
+the conditioned input contract is identical for the ZO paths
+(tests/test_pde.py covers their parity on conditioned problems).
 
 Documented tolerances (mean-squared error against the closed form on 400
 held-out interior points, per coefficient draw; solution scales are O(1) in
@@ -125,6 +125,76 @@ def test_exact_solution_satisfies_residual_per_coefficient(pde):
                                 n_active=prob.in_dim)
         r = prob.residual(est, xt)
         assert float(jnp.mean(r * r)) < prob.residual_tol, (pde, c)
+
+
+# per family: the two held-out coefficient vectors near the range's ends
+EXTREMES = {
+    "heat-10d-kappa": ((0.6,), (1.8,)),
+    "hjb-10d-lam": ((0.06,), (0.14,)),
+    "black-scholes-8d-rs": ((0.02, 0.25), (0.09, 0.55)),
+}
+
+
+@pytest.mark.parametrize("pde", sorted(FAMILIES))
+def test_true_coefficient_beats_the_opposite_extreme(pde):
+    """The coefficient slots are load-bearing: at each end of the range the
+    trained family evaluated with the TRUE coefficient is closer to the
+    closed form than the same model evaluated with the opposite end."""
+    model, params = _train_family(pde)
+    prob = model.problem
+    pts = prob.sample_collocation(jax.random.PRNGKey(7),
+                                  400)[:, :prob.in_dim]
+    a, b = EXTREMES[pde]
+    for true, other in ((a, b), (b, a)):
+        exact = prob.exact_solution(prob.attach_coeffs(pts, jnp.asarray(true)))
+        mse = {c: float(jnp.mean((model.u(params, prob.attach_coeffs(
+            pts, jnp.asarray(c))) - exact) ** 2)) for c in (true, other)}
+        assert mse[true] < mse[other], (pde, true, mse)
+
+
+def test_unconditioned_path_bit_identical_through_conditioning_seams():
+    """The seams the conditioning generalized leave the unconditioned path
+    bit for bit as it was: the default vs an explicit kappa=1 heat problem
+    (u-stencils and stacked losses), ``shared_x=None`` vs ``True`` in the
+    stacked TT dispatch, ``n_active=None`` vs ``in_dim`` in the FD
+    estimate."""
+    from repro.core import stein, tt
+    from repro.kernels import ops
+    from repro.pde.heat import HeatProblem
+    p_default, p_explicit = HeatProblem(space_dim=10), \
+        HeatProblem(space_dim=10, kappa=1.0)
+    cfg = pinn.PINNConfig(hidden=32, mode="tt", tt_rank=2, tt_L=3,
+                          pde="heat-10d", deriv="fd_fast")
+    m0 = pinn.TensorPinn(cfg, problem=p_default)
+    m1 = pinn.TensorPinn(cfg, problem=p_explicit)
+    key = jax.random.PRNGKey(0)
+    params = m0.init(key)
+    xt = p_default.sample_collocation(jax.random.fold_in(key, 1), 16)
+    sp = jax.tree.map(lambda l: jnp.broadcast_to(l, (3,) + l.shape), params)
+    stencil = lambda m: np.asarray(jax.jit(
+        lambda p: m.fd_u_stencil(p, xt, m.fd_step))(params))
+    stacked = lambda m: np.asarray(jax.jit(
+        lambda s: pinn.residual_losses_stacked(m, s, xt))(sp))
+    np.testing.assert_array_equal(stencil(m0), stencil(m1))
+    np.testing.assert_array_equal(stacked(m0), stacked(m1))
+
+    spec = tt.auto_factorize(32, 32, L=3, max_rank=2)
+    keys = jax.random.split(jax.random.fold_in(key, 2), 3)
+    stacks = tuple(jnp.stack([tt.tt_init(k, spec)[i] for k in keys])
+                   for i in range(spec.L))
+    x = jax.random.normal(jax.random.fold_in(key, 3), (16, 32))
+    np.testing.assert_array_equal(
+        np.asarray(ops.tt_linear_batched(x, stacks, spec)),
+        np.asarray(ops.tt_linear_batched(x, stacks, spec, shared_x=True)))
+
+    def fd(n_active):
+        def est(x):
+            e = stein.fd_estimate(lambda pts: m0.u(params, pts), x,
+                                  h=m0.fd_step, n_active=n_active)
+            return e.grad, e.hess_diag
+        return jax.jit(est)(xt)
+    for a, b in zip(fd(None), fd(p_default.in_dim)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_out_of_range_coefficient_rejected_at_serve_time():

@@ -414,14 +414,16 @@ def test_fused_kernel_tonn_forward_matches_unfused(monkeypatch):
 
 def test_kron_head_paper_spec_matches_generic():
     """The paper's hidden-layer ranks [1,2,1,2,1] decouple at k=2 into a
-    Kronecker product; the two-GEMM head used by the fused CPU path must
-    match the generic stacked chain: u-stencils strictly, losses at the
-    1/h² FD noise floor."""
+    Kronecker product, the split the stacked contraction kernel takes on
+    TPU.  The paper-spec stacked stencil through the kernel dispatcher
+    (fused) must match the unfused stacked chain: u-stencils strictly,
+    losses at the 1/h² FD noise floor."""
+    from repro.kernels import tt_contract
     cfg = pinn.PINNConfig(hidden=1024, mode="tt", tt_rank=2, tt_L=4,
                           deriv="fd_fast")
     cfg_f = dataclasses.replace(cfg, use_fused_kernel=True)
     model, model_f = pinn.HJBPinn(cfg), pinn.HJBPinn(cfg_f)
-    assert model_f._kron_split == 2
+    assert tt_contract.kron_split(model.specs[1]) == 2
     params = model.init(jax.random.PRNGKey(0))
     stacked = jax.tree.map(lambda p: jnp.stack([p, 1.01 * p]), params)
     xt = pinn.sample_collocation(jax.random.PRNGKey(1), 4)

@@ -97,7 +97,7 @@ def test_onn_first_step_matches_plain_reference(kernel_mode, monkeypatch):
     compares at the FD noise floor of DESIGN.md §Perf: the stencil's
     second differences amplify that rounding 1/h² = 1e4-fold, so the
     losses of two f32 paths differ by 1e-3..1e-2 relative (0.1 bounds it,
-    as ``benchmarks/zo_step.py`` does).  The first update is compared by
+    the contract's bound on losses).  The first update is compared by
     the cell's ``update1_gap`` limit: every counted leaf moves by ±lr."""
     monkeypatch.setenv("REPRO_KERNEL_MODE", kernel_mode)
     sys.path[:0] = [str(BENCH), str(BENCH / "drivers")]

@@ -27,6 +27,11 @@ EXACT_PDES = [n for n in ALL_PDES if pde.get_problem(n).has_exact_solution]
 # CPU-sized model per problem for parity tests (the 100-dim problem pays
 # 2·101+1 stencil inferences per loss, so it gets a smaller batch)
 PARITY_BATCH = {"black-scholes-100d": 4, "black-scholes-100d-rs": 4}
+# relative loss tolerance of fused vs unfused (polynomial vs libm sine):
+# the residual sums 100 second differences, each amplifying the sine's
+# ~2 ulp by 1/h², so the 100-dim problem sits at DESIGN.md §Perf's
+# contract (1e-1) rather than the lower-dimensional problems' 2e-2
+FUSED_LOSS_RTOL = {"black-scholes-100d": 1e-1}
 
 
 def _tiny_model(name: str, deriv: str = "fd_fast", **over) -> pinn.TensorPinn:
@@ -199,17 +204,19 @@ def test_photonic_noise_stacked_matches_sequential(mode):
                                rtol=2e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("name", ["heat-10d", "helmholtz-2d"])
+@pytest.mark.parametrize("name", ALL_PDES)
 def test_fused_kernel_stacked_matches_unfused_per_problem(name):
-    """use_fused_kernel (stacked TT contraction + Kronecker head +
-    polynomial sine) against the unfused chain on non-HJB problems:
-    u-stencils strictly, losses at the 1/h² FD noise floor (DESIGN.md)."""
+    """use_fused_kernel (stacked TT contraction through the kernel
+    dispatcher + polynomial sine) against the unfused chain on every
+    registered problem: u-stencils strictly, losses at the 1/h² FD noise
+    floor (DESIGN.md)."""
     model = _tiny_model(name)
     model_f = pinn.TensorPinn(
         dataclasses.replace(model.cfg, use_fused_kernel=True))
     plist = [model.init(k) for k in jax.random.split(jax.random.PRNGKey(0), 3)]
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *plist)
-    xt = model.problem.sample_collocation(jax.random.PRNGKey(1), 6)
+    xt = model.problem.sample_collocation(jax.random.PRNGKey(1),
+                                          PARITY_BATCH.get(name, 6))
     h = model.fd_step
     np.testing.assert_allclose(
         np.asarray(model_f.fd_u_stencil_stacked(stacked, xt, h)),
@@ -218,7 +225,7 @@ def test_fused_kernel_stacked_matches_unfused_per_problem(name):
     np.testing.assert_allclose(
         np.asarray(pinn.residual_losses_stacked(model_f, stacked, xt)),
         np.asarray(pinn.residual_losses_stacked(model, stacked, xt)),
-        rtol=2e-2, atol=1e-4)
+        rtol=FUSED_LOSS_RTOL.get(name, 2e-2), atol=1e-4)
 
 
 # --------------------------------------------------------- boundary loss L_b
