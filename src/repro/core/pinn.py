@@ -546,8 +546,13 @@ class TensorPinn:
         goes through ``_layer_matvec_stacked``: on TPU the stacked
         contraction kernel takes the paper spec's Kronecker split itself
         (``ops.tt_path`` "kron")."""
+        return self._head_stacked(stacked,
+                                  self._layer_matvec_stacked(stacked, 1, a))
+
+    def _head_stacked(self, stacked: dict, z: jax.Array) -> jax.Array:
+        """``f = sin(z + b1) @ w2ᵀ + b2`` on the hidden layer's (P, B',
+        hidden) pre-activations → (P, B') f-values."""
         from repro.kernels import ops
-        z = self._layer_matvec_stacked(stacked, 1, a)
         b1, w2 = stacked["b1"], stacked["w2"]
         if ops.kernel_mode() == "pallas":
             # XLA on TPU tiles a stacked reduction's output by P, so entry
@@ -577,13 +582,31 @@ class TensorPinn:
         eye = jnp.eye(self.in_dim, self.in_pad, dtype=jnp.float32)
         cols = self._layer_matvec_stacked(stacked, 0, eye)            # (P,A,H)
         hcols = h * cols
-        z = jnp.concatenate(
-            [z0[:, None],
-             z0[:, None] + hcols[:, :, None],                         # +h e_i
-             z0[:, None] - hcols[:, :, None]], axis=1)         # (P,2A+1,B,H)
-        a = self._sin(z).reshape(P, (2 * A + 1) * B, cfg.hidden)
-        f = self._f_head_stacked(stacked, a).reshape(P, 2 * A + 1, B)
+        if self.stencil_prologue():
+            # the kernel builds sin(z0 ± hcols) in VMEM, B rows padded to
+            # Bp a slot; the pad rows' f-values are dropped
+            from repro.kernels import ops
+            z = ops.tt_linear_stencil(z0, hcols, stacked["cores1"],
+                                      self.specs[1])
+            f = self._head_stacked(stacked, z).reshape(P, 2 * A + 1, -1)
+            f = f[:, :, :B]
+        else:
+            z = jnp.concatenate(
+                [z0[:, None],
+                 z0[:, None] + hcols[:, :, None],                     # +h e_i
+                 z0[:, None] - hcols[:, :, None]], axis=1)     # (P,2A+1,B,H)
+            a = self._sin(z).reshape(P, (2 * A + 1) * B, cfg.hidden)
+            f = self._f_head_stacked(stacked, a).reshape(P, 2 * A + 1, B)
         return self.problem.ansatz(f, pde_lib.fd_stencil_points(xt, h, A))
+
+    def stencil_prologue(self) -> bool:
+        """True iff ``fd_u_stencil_stacked`` hands layer 1 the stencil's
+        rank-1 factors (z0, h·W0 e_i) and the ``tt_contract`` kernel builds
+        the rows itself: tt and tonn layers on the unquantized kernel
+        (``ops.tt_stencil_prologue``).  Static per model and kernel mode."""
+        from repro.kernels import ops
+        return (self.cfg.mode in ("tt", "tonn") and self.cfg.use_fused_kernel
+                and ops.tt_stencil_prologue(self.specs[1], quant=self._quant))
 
     def f_stacked(self, stacked: dict, xt: jax.Array) -> jax.Array:
         """Base network for P stacked (prepared) parameter sets over a

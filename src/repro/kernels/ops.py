@@ -27,7 +27,8 @@ from repro.kernels import ref as _ref
 from repro.kernels import tt_contract as _ttc
 
 __all__ = ["kernel_mode", "tt_impl", "tt_path", "mesh_impl", "mesh_path",
-           "tt_linear", "tt_linear_batched", "mesh_apply_stacked",
+           "tt_linear", "tt_linear_batched", "tt_stencil_prologue",
+           "tt_linear_stencil", "mesh_apply_stacked",
            "attention", "KERNEL_MODES"]
 
 KERNEL_MODES = ("pallas", "interpret", "ref")
@@ -148,6 +149,29 @@ def tt_linear_batched(x: jax.Array, cores: Sequence[jax.Array],
     return _ttc.tt_contract_batched(x, tuple(cores), spec,
                                     interpret=(mode == "interpret"),
                                     shared_x=shared_x)
+
+
+def tt_stencil_prologue(spec: tt_lib.TTSpec, mode: str | None = None,
+                        quant=None) -> bool:
+    """True iff ``tt_linear_stencil`` can take ``spec``: the unquantized
+    kernel (pallas or interpret), which builds the FD stencil's rows from
+    their rank-1 factors in VMEM.  Static per spec and mode."""
+    return tt_impl(spec, mode) != "ref" and not _weight_quant(quant)
+
+
+def tt_linear_stencil(z0: jax.Array, hcols: jax.Array,
+                      cores: Sequence[jax.Array], spec: tt_lib.TTSpec,
+                      mode: str | None = None) -> jax.Array:
+    """Layer 1 of the rank-1 FD stencil on P stacked core sets:
+    ``tt_contract.tt_contract_stencil`` (rows ``sin(z0 ± hcols)`` built in
+    the kernel, B padded to a multiple of 8 per slot).  Only where
+    ``tt_stencil_prologue(spec, mode)``."""
+    if not tt_stencil_prologue(spec, mode):
+        raise ValueError(f"the stencil prologue needs the kernel; {spec} "
+                         f"takes {tt_impl(spec, mode)!r}")
+    return _ttc.tt_contract_stencil(z0, hcols, tuple(cores), spec,
+                                    interpret=(tt_impl(spec, mode)
+                                               == "interpret"))
 
 
 def mesh_apply_stacked(layout, phases: jax.Array, diag: jax.Array,

@@ -52,12 +52,24 @@ exact, and the FD residual, which amplifies rounding in u by 1/h²
 (DESIGN.md §Perf), sees f32 arithmetic as on the jnp oracle.  Each row's
 result depends on that row alone, whatever the tile or the stack size.
 
-HBM traffic per call is the x tiles, the y tiles, the cores and the one-hot
-tables; the tables are read once per call, whatever the batch.
+Both bodies read their input through ``x_tile(lanes)``, one tile of rows
+on the given lanes, from one of two sources:
+
+  * x in HBM (``tt_contract_batched``): a ``(tile, N)`` block per grid
+    step.  HBM traffic per call is the x tiles, the y tiles, the cores and
+    the one-hot tables; the tables are read once per call, whatever the
+    batch.
+  * the rank-1 FD stencil (``tt_contract_stencil``): the rows
+    ``sin(z0 ± hcols[i])`` are built in VMEM from ``z0 (P, B, N)`` and the
+    shifts ``hcols (P, A, N)``, both one block per ``p``, resident across
+    its row tiles; a tile is whole slots of ``Bp = roundup(B, 8)`` rows.
+    HBM traffic per call is z0 and the shifts once per ``p``, the y tiles,
+    the cores and the tables: the ``(2A+1)·B`` input rows (194 MB at the
+    paper's sizes) are never read.
 
 ``tt_contract_batched`` adds a leading perturbation axis P: grid
-``(P, batch-tiles)``, one core set per ``p`` and its matrices rebuilt at
-the first batch tile of every ``p``, so an entire ZO loss sweep (all N+1
+``(P, row-tiles)``, one core set per ``p`` and its matrices rebuilt at
+the first row tile of every ``p``, so an entire ZO loss sweep (all N+1
 perturbed models) is ONE launch.  x may be shared across P (its index map
 ignores p) or carry its own P axis.  ``tt_contract`` is the P = 1 case.
 ``fits_vmem`` says whether a spec's W and tables fit the kernel's VMEM
@@ -74,10 +86,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core import fastmath
 from repro.core import tt as tt_lib
 from repro.kernels import quant as quant_lib
 
-__all__ = ["tt_contract", "tt_contract_batched",
+__all__ = ["tt_contract", "tt_contract_batched", "tt_contract_stencil",
            "tt_contract_batched_quant", "fits_vmem", "kron_split",
            "split_spec", "kron_factors"]
 
@@ -228,11 +241,17 @@ def _row_block(m_dim: int) -> int:
     return m_dim
 
 
+def _tile_cap(spec: tt_lib.TTSpec) -> int:
+    """Most rows a tile may hold: its x and y blocks, double-buffered, fill
+    ``_TILE_BUDGET_BYTES``."""
+    per_row = 2 * 4 * (spec.in_dim + spec.out_dim)
+    return max(16, (_TILE_BUDGET_BYTES // per_row) // 16 * 16)
+
+
 def _batch_tile(rows: int, spec: tt_lib.TTSpec) -> int:
     """Balanced batch tile: as many tiles as the budget needs, each a
     multiple of 16 rows (bf16 tiling), or the whole batch if it fits."""
-    per_row = 2 * 4 * (spec.in_dim + spec.out_dim)
-    cap = max(16, (_TILE_BUDGET_BYTES // per_row) // 16 * 16)
+    cap = _tile_cap(spec)
     if rows <= cap:
         return rows
     n_tiles = -(-rows // cap)
@@ -355,9 +374,10 @@ def _kron_body(spec, cores, tables, x_tile, o_ref, er_ref, h_ref, c_ref,
             y_ref[ob, :bt].astype(o_ref.dtype)
 
 
-def _kernel(spec: tt_lib.TTSpec, shared_x: bool, quantized: bool, *refs):
+def _kernel(spec: tt_lib.TTSpec, x_tile_of, n_x: int, quantized: bool,
+            *refs):
     L = spec.L
-    x_ref, refs = refs[0], refs[1:]
+    x_refs, refs = refs[:n_x], refs[n_x:]
     if quantized:
         q_refs, s_refs, e_refs = refs[:L], refs[L:2 * L], refs[2 * L:3 * L]
         refs = refs[3 * L:]
@@ -374,11 +394,47 @@ def _kernel(spec: tt_lib.TTSpec, shared_x: bool, quantized: bool, *refs):
                     * _dot(s_refs[k][0], e_refs[k][...]) for k in range(L)]
         return [c[0].astype(jnp.float32) for c in c_refs]
 
-    def x_tile(lanes):
-        return x_ref[:, lanes] if shared_x else x_ref[0, :, lanes]
-
     body = _kron_body if kron_factors(spec) else _dense_body
-    body(spec, cores, tables, x_tile, o_ref, *scratch)
+    body(spec, cores, tables, x_tile_of(*x_refs), o_ref, *scratch)
+
+
+def _x_source(x: jax.Array, spec: tt_lib.TTSpec, shared_x: bool) -> tuple:
+    """``(operands, block specs, x_tile_of, rows, tile)`` of an input read
+    from HBM: one ``(tile, N)`` block of x per grid step."""
+    rows = x.shape[-2]
+    bt = _batch_tile(rows, spec)
+    if shared_x:
+        blk = pl.BlockSpec((bt, spec.in_dim), lambda p, i: (i, 0))
+        x_tile_of = lambda x_ref: lambda lanes: x_ref[:, lanes]
+    else:
+        blk = pl.BlockSpec((1, bt, spec.in_dim), lambda p, i: (p, i, 0))
+        x_tile_of = lambda x_ref: lambda lanes: x_ref[0, :, lanes]
+    return (x,), (blk,), x_tile_of, rows, bt
+
+
+def _stencil_source(z0: jax.Array, shifts: jax.Array,
+                    spec: tt_lib.TTSpec) -> tuple:
+    """``_x_source`` for rows built in VMEM: slot s of ``Bp`` rows is
+    ``sin(z0[p] + shifts[p, s])``.  A tile is whole slots, and its last
+    slots past the end repeat the final one (their rows are not written)."""
+    _, bp, width = z0.shape
+    n_slots = shifts.shape[1]
+    per_tile = min(n_slots, max(1, _tile_cap(spec) // bp))
+    per_tile = -(-n_slots // -(-n_slots // per_tile))     # balanced tiles
+
+    def x_tile_of(z0_ref, shift_ref):
+        def x_tile(lanes):
+            z = z0_ref[0, :, lanes]
+            first = pl.program_id(1) * per_tile
+            rows = [z + shift_ref[0, jnp.minimum(first + t, n_slots - 1), :,
+                                  lanes] for t in range(per_tile)]
+            return fastmath.fast_sin(jnp.concatenate(rows, axis=0))
+        return x_tile
+
+    return ((z0, shifts),
+            (pl.BlockSpec((1, bp, width), lambda p, i: (p, 0, 0)),
+             pl.BlockSpec((1, n_slots, 1, width), lambda p, i: (p, 0, 0, 0))),
+            x_tile_of, n_slots * bp, per_tile * bp)
 
 
 def _split_batch_axes(x: jax.Array, P: int, spec: tt_lib.TTSpec,
@@ -402,12 +458,12 @@ def _split_batch_axes(x: jax.Array, P: int, spec: tt_lib.TTSpec,
     return x.reshape(P, -1, spec.in_dim), batch_shape, False
 
 
-def _launch(x: jax.Array, core_args: list, core_specs: list,
-            spec: tt_lib.TTSpec, P: int, flat_lens: tuple, shared_x: bool,
-            quantized: bool, interpret: bool) -> jax.Array:
-    """One ``pallas_call`` over the ``(P, batch-tiles)`` grid."""
-    B = x.shape[-2]
-    bt = _batch_tile(B, spec)
+def _launch(source: tuple, core_args: list, core_specs: list,
+            spec: tt_lib.TTSpec, P: int, flat_lens: tuple, quantized: bool,
+            interpret: bool) -> jax.Array:
+    """One ``pallas_call`` over the ``(P, row-tiles)`` grid; ``source`` is
+    ``_x_source``'s or ``_stencil_source``'s."""
+    x_args, x_specs, x_tile_of, n_rows, bt = source
     const = lambda a: pl.BlockSpec(a.shape, lambda p, i: (0,) * a.ndim)
     if kron_factors(spec):
         left, right, _, a = _kron_geometry(spec)
@@ -426,23 +482,20 @@ def _launch(x: jax.Array, core_args: list, core_specs: list,
         scratch = [pltpu.VMEM((spec.out_dim, spec.in_dim), jnp.float32)]
         tabs = _tables(spec, flat_lens)
     tables = [jnp.asarray(t) for tab in tabs for t in tab]
-    if shared_x:
-        x_spec = pl.BlockSpec((bt, spec.in_dim), lambda p, i: (i, 0))
-    else:
-        x_spec = pl.BlockSpec((1, bt, spec.in_dim), lambda p, i: (p, i, 0))
     return pl.pallas_call(
-        functools.partial(_kernel, spec, shared_x, quantized),
-        grid=(P, pl.cdiv(B, bt)),
-        in_specs=[x_spec] + core_specs + [const(t) for t in tables],
+        functools.partial(_kernel, spec, x_tile_of, len(x_args), quantized),
+        grid=(P, pl.cdiv(n_rows, bt)),
+        in_specs=list(x_specs) + core_specs + [const(t) for t in tables],
         out_specs=pl.BlockSpec((1, bt, spec.out_dim), lambda p, i: (p, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((P, B, spec.out_dim), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((P, n_rows, spec.out_dim),
+                                       x_args[0].dtype),
         scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=interpret,
         name="tt_contract_quant" if quantized else "tt_contract",
-    )(x, *core_args, *tables)
+    )(*x_args, *core_args, *tables)
 
 
 def _row_spec(length: int) -> pl.BlockSpec:
@@ -474,9 +527,40 @@ def tt_contract_batched(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
     x, batch_shape, shared_x = _split_batch_axes(x, P, spec, shared_x)
     flat_lens = tuple(int(np.prod(s)) for s in spec.core_shapes)
     flat = [c.reshape(P, 1, -1) for c in cores]
-    y = _launch(x, flat, [_row_spec(f) for f in flat_lens], spec, P,
-                flat_lens, shared_x, False, interpret)
+    y = _launch(_x_source(x, spec, shared_x), flat,
+                [_row_spec(f) for f in flat_lens], spec, P, flat_lens, False,
+                interpret)
     return y.reshape((P,) + batch_shape + (spec.out_dim,))
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "interpret"))
+def tt_contract_stencil(z0: jax.Array, hcols: jax.Array, cores: tuple,
+                        spec: tt_lib.TTSpec,
+                        interpret: bool = False) -> jax.Array:
+    """``tt_contract_batched`` on the rank-1 FD stencil's rows, built in
+    VMEM: the layer after a linear one whose input the stencil shifts by
+    ±h·e_i, so its pre-activations are ``z0 ± hcols[i]``.
+
+    z0: ``(P, B, N)`` pre-activations at the B points; hcols: ``(P, A, N)``,
+    the A shifts ``h·W e_i``; cores: ``(P, r, m, n, r')`` stacks.  Slot 0
+    of a point is ``sin(z0)``, slot i in 1..A ``sin(z0 + hcols[i-1])``,
+    slot A+i ``sin(z0 − hcols[i-1])`` (``fastmath.fast_sin``); each slot
+    holds ``Bp = roundup(B, 8)`` rows, the last ``Bp − B`` from zero rows
+    of z0.  Returns ``(P, (2A+1)·Bp, M)``, slot-major; rows ``s·Bp + b``
+    with b < B are the stencil's, the others are finite and read by
+    nobody.  The ``(P, (2A+1)·B, N)`` activations never exist in HBM.
+    """
+    P, B, _ = z0.shape
+    bp = -(-B // 8) * 8
+    z0 = jnp.pad(z0, ((0, 0), (0, bp - B), (0, 0)))
+    # z0 + (−hcols) is z0 − hcols to the bit; slot 0 adds a zero row
+    shifts = jnp.concatenate([jnp.zeros_like(hcols[:, :1]), hcols, -hcols],
+                             axis=1)[:, :, None]
+    flat_lens = tuple(int(np.prod(s)) for s in spec.core_shapes)
+    flat = [c.reshape(P, 1, -1) for c in cores]
+    return _launch(_stencil_source(z0, shifts, spec), flat,
+                   [_row_spec(f) for f in flat_lens], spec, P, flat_lens,
+                   False, interpret)
 
 
 def tt_contract(x: jax.Array, cores: tuple, spec: tt_lib.TTSpec,
@@ -527,6 +611,6 @@ def tt_contract_batched_quant(x: jax.Array, cores: tuple,
     core_specs = [_row_spec(q.shape[-1]) for q in qs] \
         + [_row_spec(s.shape[-1]) for s in ss] \
         + [pl.BlockSpec(e.shape, lambda p, i: (0, 0)) for e in spreads]
-    y = _launch(x, qs + ss + spreads, core_specs, spec, P, flat_lens,
-                shared_x, True, interpret)
+    y = _launch(_x_source(x, spec, shared_x), qs + ss + spreads, core_specs,
+                spec, P, flat_lens, True, interpret)
     return y.reshape((P,) + batch_shape + (spec.out_dim,))

@@ -173,6 +173,98 @@ def test_tt_impl_takes_kernel_only_when_w_fits_vmem(spec, want):
     assert ops.tt_impl(spec, "ref") == "ref"
 
 
+# ------------------------------------ tt_contract_stencil (layer-1 prologue)
+
+STENCIL_SPECS = [tt.PAPER_TONN_SPEC,                    # the Kronecker body
+                 tt.auto_factorize(1024, 1024, L=4, max_rank=4)]   # dense
+
+
+def _stencil_factors(spec, P, B, A, seed=0):
+    keys = jax.random.split(jax.random.PRNGKey(seed), P)
+    stacks = tuple(jnp.stack([tt.tt_init(k, spec)[i] for k in keys])
+                   for i in range(spec.L))
+    z0 = jax.random.normal(jax.random.PRNGKey(seed + 1), (P, B, spec.in_dim))
+    hcols = 1e-2 * jax.random.normal(jax.random.PRNGKey(seed + 2),
+                                     (P, A, spec.in_dim))
+    return z0, hcols, stacks
+
+
+@pytest.mark.parametrize("A", [21, 3])
+@pytest.mark.parametrize("B", [100, 37])
+@pytest.mark.parametrize("P", [1, 3])
+@pytest.mark.parametrize("spec", STENCIL_SPECS, ids=["kron", "dense"])
+def test_tt_contract_stencil_matches_rows_through_batched(spec, P, B, A):
+    """The prologue launch (rows ``sin(z0 ± hcols)`` built in the kernel,
+    B padded to whole sublanes a slot) equals the rows built in XLA
+    (concat, ``fast_sin``, reshape) through ``tt_contract_batched``.  Not
+    to the bit on the CPU: XLA:CPU contracts ``fast_sin``'s multiply-adds
+    into FMAs differently inside and outside the kernel (≈ 2 ulp of a
+    sine), which moves y by at most 4.8e-7 here; 1e-6 holds."""
+    from repro.core import fastmath
+    from repro.kernels import tt_contract as ttc
+    z0, hcols, stacks = _stencil_factors(spec, P, B, A)
+    y = np.asarray(ttc.tt_contract_stencil(z0, hcols, stacks, spec,
+                                           interpret=True))
+    bp = -(-B // 8) * 8
+    assert y.shape == (P, (2 * A + 1) * bp, spec.out_dim)
+    y = y.reshape(P, 2 * A + 1, bp, spec.out_dim)
+    z = jnp.concatenate([z0[:, None], z0[:, None] + hcols[:, :, None],
+                         z0[:, None] - hcols[:, :, None]], axis=1)
+    a = fastmath.fast_sin(z).reshape(P, (2 * A + 1) * B, spec.in_dim)
+    want = np.asarray(ttc.tt_contract_batched(a, stacks, spec,
+                                              interpret=True))
+    np.testing.assert_allclose(y[:, :, :B],
+                               want.reshape(P, 2 * A + 1, B, spec.out_dim),
+                               atol=1e-6, rtol=0)
+    assert np.isfinite(y).all()               # the pad rows too
+
+
+@pytest.mark.parametrize("spec", STENCIL_SPECS, ids=["kron", "dense"])
+def test_tt_contract_stencil_rows_do_not_depend_on_stack_size(spec):
+    """Entry p of an 11-entry launch equals the 1-entry launch on its
+    cores, bit for bit: perturbation sharding evaluates slices of the
+    stack (DESIGN.md §Distributed)."""
+    from repro.kernels import tt_contract as ttc
+    P, B, A = 11, 37, 3
+    z0, hcols, stacks = _stencil_factors(spec, P, B, A)
+    full = np.asarray(ttc.tt_contract_stencil(z0, hcols, stacks, spec,
+                                              interpret=True))
+    for p in (0, 6, 10):
+        one = ttc.tt_contract_stencil(z0[p:p + 1], hcols[p:p + 1],
+                                      tuple(s[p:p + 1] for s in stacks),
+                                      spec, interpret=True)
+        np.testing.assert_array_equal(np.asarray(one)[0], full[p])
+
+
+@pytest.mark.parametrize("mode,kernel_mode,quant,want", [
+    ("tonn", "pallas", None, True),
+    ("tonn", "interpret", None, True),
+    ("tt", "pallas", None, True),
+    ("tt", "interpret", None, True),
+    ("tonn", "ref", None, False),
+    ("tt", "ref", None, False),
+    ("tonn", "pallas", "int8", False),
+    ("tt", "interpret", "int8", False),
+    ("onn", "pallas", None, False),
+    ("dense", "pallas", None, False),
+])
+def test_stencil_prologue_is_taken_by_tt_layers_on_the_kernel(
+        mode, kernel_mode, quant, want, monkeypatch):
+    """Which configurations build layer 1's stencil rows inside the
+    kernel: tt and tonn with the paper spec on the unquantized kernel;
+    not onn or dense (per-entry matmuls), the quantized kernel or ref."""
+    from repro.configs.hjb_pinn import pinn_config
+    from repro.core import pinn
+    from repro.kernels import quant as quant_lib
+    monkeypatch.setenv("REPRO_KERNEL_MODE", kernel_mode)
+    q = (quant_lib.QuantConfig(enabled=True, dtype=quant) if quant
+         else quant_lib.QuantConfig())
+    model = pinn.TensorPinn(pinn_config("hjb-20d", mode, quant=q))
+    if mode in ("tt", "tonn"):
+        assert model.specs[1] == tt.PAPER_TONN_SPEC
+    assert model.stencil_prologue() is want
+
+
 def _deep_qr_layout(ports: int = 40):
     """A Givens-QR (Reck) layout: 2·ports − 3 levels, not rectangular."""
     u = np.linalg.qr(np.random.RandomState(3).randn(ports, ports))[0]

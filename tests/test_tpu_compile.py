@@ -11,6 +11,7 @@ at no chip time.  The topology is described inside a fixture, so no worker
 loads the TPU library while collecting.
 """
 
+import math
 import os
 import re
 
@@ -97,6 +98,48 @@ def test_tt_contract_dense_body_compiles(shape):
     _assert_kernel(
         lambda x, *c: tt_contract.tt_contract_batched(x, c, spec),
         shape((P, STENCIL_ROWS, spec.in_dim)), *cores)
+
+
+@pytest.mark.parametrize("spec", [SPEC, tt.auto_factorize(1024, 1024, L=4,
+                                                          max_rank=2)],
+                         ids=["kron", "dense"])
+def test_tt_contract_stencil_compiles(shape, spec):
+    """Layer 1's launch with the stencil prologue at the paper's width:
+    z0 of the 100 points and the 21 shifts, the rows built in VMEM."""
+    cores = [shape((P,) + s) for s in spec.core_shapes]
+    _assert_kernel(
+        lambda z, h, *c: tt_contract.tt_contract_stencil(z, h, c, spec),
+        shape((P, 100, spec.in_dim)), shape((P, 21, spec.in_dim)), *cores)
+
+
+def test_tonn_step_hands_layer1_no_stencil_rows(one_chip, monkeypatch):
+    """In the compiled tonn loss, no ``tt_contract`` call takes the
+    (P, 43·100, 1024) stencil activations: layer 1 gets z0 and the shifts
+    and writes its (P, 43·104, 1024) rows slot by slot."""
+    from repro.core import zoo
+    monkeypatch.setenv("REPRO_KERNEL_MODE", "pallas")
+    model = pinn.TensorPinn(pinn_config("hjb-20d", "tonn", noise=True))
+    assert model.stencil_prologue()
+    key = jax.random.PRNGKey(0)
+    sds = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+    params = jax.eval_shape(model.init, key)
+    stack = jax.eval_shape(lambda p: zoo.sample_perturbations(
+        key, p, P, model.trainable_mask(p)), params)
+    noise = jax.eval_shape(model.sample_noise, key)
+    xt = jax.ShapeDtypeStruct((100, model.problem.net_dim), jnp.float32,
+                              sharding=one_chip)
+    text = jax.jit(lambda sp, x, nz: pinn.residual_losses_stacked(
+        model, sp, x, nz)).lower(jax.tree.map(sds, stack), xt,
+                                 jax.tree.map(sds, noise)).compile().as_text()
+    calls = re.findall(r"%tt_contract(?:\.\d+)? = f32\[([\d,]*)\][^\n]*?"
+                       r"operand_layout_constraints=\{(.*?\})\}", text)
+    outs = sorted(tuple(int(d) for d in out.split(",")) for out, _ in calls)
+    assert outs == [(P, 21, 1024), (P, 100, 1024), (P, 43 * 104, 1024)]
+    for _, operands in calls:
+        sizes = [math.prod(int(d) for d in dims.split(","))
+                 for dims in re.findall(r"f32\[([\d,]*)\]", operands)]
+        assert len(sizes) > SPEC.L          # x, the cores and the tables
+        assert P * STENCIL_ROWS * 1024 not in sizes, operands
 
 
 def test_tt_contract_batched_quant_int8_compiles(shape):
